@@ -6,7 +6,7 @@ Fig. 13 analog (placement/implementation strategies on this host):
     resident paged snapshot (the TPU-native design: metadata+pages as
     persistent device arrays, one jitted dispatch per batch);
   * pallas_interpret— the TPU kernel semantics executed in interpret mode
-    (correctness path; on-TPU perf is modeled in EXPERIMENTS.md §Roofline).
+    (correctness path on the CPU; it says nothing about on-TPU speed).
 Fig. 9's sampling-speedup claim maps to vectorized vs cpu_oracle here.
 
 Timing hygiene: every variant reports BOTH the first call (compile +

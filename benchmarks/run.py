@@ -1,5 +1,10 @@
 """Benchmark harness entry point — one module per paper table/figure.
-Prints ``name,us_per_call,derived`` CSV rows (benchmarks/common.py)."""
+Prints ``name,us_per_call,derived`` CSV rows (benchmarks/common.py).
+
+The ``multihost`` bench launches CPU-forced worker processes (a
+multi-process simulation of a fleet, ``repro.launch.multihost``); this
+process has already touched JAX, so on an accelerator host that bench
+is refused rather than run on the CPU under a chip's name."""
 from __future__ import annotations
 
 import argparse
@@ -12,6 +17,7 @@ for _p in (str(_ROOT), str(_ROOT / "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import get_logger
 
 log = get_logger("bench")
@@ -52,6 +58,9 @@ def main() -> None:
                   f"available: {', '.join(benches)}")
         sys.exit(2)
 
+    enable_compile_cache()
+    import jax
+    backend = jax.default_backend()
     print("name,us_per_call,derived")
     failed = []
     for name, fn in benches.items():
@@ -59,6 +68,11 @@ def main() -> None:
             continue
         t0 = time.time()
         try:
+            if name == "multihost" and backend != "cpu":
+                raise RuntimeError(
+                    f"refused on {backend}: the multihost bench spawns "
+                    "CPU-forced workers from a process that holds the "
+                    "accelerator (a CPU simulation, not a chip run)")
             fn()
         except Exception as e:  # keep the harness going, surface failure
             print(f"{name}/FAILED,0,{type(e).__name__}:{e}",

@@ -37,8 +37,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist.sharding import shard_map
-
 PyTree = Any
 MeshOrAxes = Union[Mesh, str, Sequence[str]]
 
@@ -71,9 +69,9 @@ def _run(fn, leaves: Tuple[jax.Array, ...], mesh_or_axes: MeshOrAxes):
     if isinstance(mesh_or_axes, Mesh):
         mesh = mesh_or_axes
         axes = tuple(mesh.axis_names)
-        wrapped = shard_map(lambda t: fn(t, axes), mesh=mesh,
-                            in_specs=(P(),), out_specs=P(),
-                            check_vma=False)
+        wrapped = jax.shard_map(lambda t: fn(t, axes), mesh=mesh,
+                                in_specs=(P(),), out_specs=P(),
+                                check_vma=False)
         return wrapped(leaves)
     axes = ((mesh_or_axes,) if isinstance(mesh_or_axes, str)
             else tuple(mesh_or_axes))

@@ -87,7 +87,6 @@ from repro.core.partition import Dispatcher, GraphPartition
 from repro.core.scheduler import DistributedSamplerSystem
 from repro.data.events import EventStream
 from repro.dist import collectives as C
-from repro.dist.sharding import shard_map
 from repro.dist.transport import LocalTransport, SamplingTransport
 from repro.obs import trace
 
@@ -412,7 +411,7 @@ class DistributedContinuousTrainer(ContinuousTrainer):
             new_err = jax.tree.map(lambda x: x[None], new_err)
             return grads, loss, (scores, labels, w), new_err
 
-        smap_train = shard_map(
+        smap_train = jax.shard_map(
             train_shard, mesh=self.mesh,
             in_specs=(P(), P("dp"), P("dp")),
             out_specs=(P(), P(), (P("dp"), P("dp"), P("dp")), P("dp")),
@@ -437,7 +436,7 @@ class DistributedContinuousTrainer(ContinuousTrainer):
             return (lax.psum(loss * cnt, "dp") / total,
                     g(scores), g(labels), g(w))
 
-        smap_eval = shard_map(
+        smap_eval = jax.shard_map(
             eval_shard, mesh=self.mesh,
             in_specs=(P(), P("dp")),
             out_specs=(P(), P(), P(), P()),

@@ -369,20 +369,3 @@ def gather_fsdp(params: PyTree) -> PyTree:
             leaf, NamedSharding(mesh, P(*spec)))
 
     return jax.tree_util.tree_map_with_path(one, params)
-
-
-# ---------------------------------------------------------------------------
-# shard_map compatibility (jax.shard_map landed after 0.4.x; older
-# releases expose jax.experimental.shard_map with `check_rep` instead of
-# `check_vma`)
-# ---------------------------------------------------------------------------
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map. check_vma maps onto check_rep."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -80,7 +82,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
 
 
 def flash_attention_kernel(q, k, v, *, causal: bool = True, qb: int = 128,
-                           kb: int = 128, interpret: bool = True):
+                           kb: int = 128):
     """q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D) with Hq % Hkv == 0.
     Sq % qb == 0 and Skv % kb == 0 (ops.py pads)."""
     B, Sq, Hq, D = q.shape
@@ -96,7 +98,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, qb: int = 128,
                            lambda b, h, qi, ki: (b, ki, h // G, 0))
     o_spec = pl.BlockSpec((1, qb, 1, D), lambda b, h, qi, ki: (b, qi, h, 0))
 
-    fn = pl.pallas_call(
+    fn = pallas_call(
         functools.partial(_kernel, qb=qb, kb=kb, n_kv=n_kv, causal=causal,
                           scale=D ** -0.5),
         grid=grid,
@@ -108,6 +110,5 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, qb: int = 128,
             pltpu.VMEM((qb, 1), jnp.float32),   # running sum
             pltpu.VMEM((qb, D), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
     )
     return fn(q, k, v)

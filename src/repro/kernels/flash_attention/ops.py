@@ -11,9 +11,9 @@ from repro.kernels.flash_attention.flash_attention import (
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("causal", "qb", "kb", "interpret"))
+                   static_argnames=("causal", "qb", "kb"))
 def flash_attention_pallas(q, k, v, *, causal: bool = True, qb: int = 128,
-                           kb: int = 128, interpret: bool = True):
+                           kb: int = 128):
     B, Sq, Hq, D = q.shape
     Skv = k.shape[1]
     qb = min(qb, max(8, Sq))
@@ -31,6 +31,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, qb: int = 128,
         assert causal or pk == 0, "non-causal needs Skv % kb == 0"
         k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    out = flash_attention_kernel(q, k, v, causal=causal, qb=qb, kb=kb,
-                                 interpret=interpret)
+    out = flash_attention_kernel(q, k, v, causal=causal, qb=qb, kb=kb)
     return out[:, :Sq]

@@ -11,9 +11,9 @@ from repro.kernels.selective_scan.selective_scan import (
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("chunk", "dtile", "interpret"))
+                   static_argnames=("chunk", "dtile"))
 def selective_scan_pallas(dt, x, A, Bt, Ct, h0, *, chunk: int = 16,
-                          dtile: int = 128, interpret: bool = True):
+                          dtile: int = 128):
     B, L, Din = x.shape
     pad = (-L) % chunk
     if pad:
@@ -26,5 +26,5 @@ def selective_scan_pallas(dt, x, A, Bt, Ct, h0, *, chunk: int = 16,
         dt.astype(jnp.float32), x.astype(jnp.float32),
         A.astype(jnp.float32), Bt.astype(jnp.float32),
         Ct.astype(jnp.float32), h0.astype(jnp.float32),
-        chunk=chunk, dtile=dtile, interpret=interpret)
+        chunk=chunk, dtile=dtile)
     return y[:, :L], h_last

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 
 def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref,
             y_ref, hout_ref, h_scratch, *, chunk: int, dtile: int,
@@ -55,7 +57,7 @@ def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref,
 
 
 def selective_scan_kernel(dt, x, A, Bt, Ct, h0, *, chunk: int = 16,
-                          dtile: int = 128, interpret: bool = True):
+                          dtile: int = 128):
     """dt, x: (B, L, Din) f32; A: (Din, N); Bt, Ct: (B, L, N);
     h0: (B, Din, N). Returns (y (B, L, Din) f32, h_last)."""
     B, L, Din = x.shape
@@ -72,7 +74,7 @@ def selective_scan_kernel(dt, x, A, Bt, Ct, h0, *, chunk: int = 16,
     a_spec = pl.BlockSpec((dtile, N), lambda b, d, l: (d, 0))
     h_spec = pl.BlockSpec((1, dtile, N), lambda b, d, l: (b, d, 0))
 
-    fn = pl.pallas_call(
+    fn = pallas_call(
         functools.partial(_kernel, chunk=chunk, dtile=dtile, n=N,
                           n_chunks=nL),
         grid=grid,
@@ -81,6 +83,5 @@ def selective_scan_kernel(dt, x, A, Bt, Ct, h0, *, chunk: int = 16,
         out_shape=[jax.ShapeDtypeStruct((B, L, Din), jnp.float32),
                    jax.ShapeDtypeStruct((B, Din, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dtile, N), jnp.float32)],
-        interpret=interpret,
     )
     return fn(dt, x, Bt, Ct, A, h0)

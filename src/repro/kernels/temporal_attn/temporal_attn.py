@@ -3,11 +3,15 @@
 The compute hot-spot fed by the temporal sampler: each target attends
 over its K sampled neighbors (K = fanout, small) — thousands of tiny
 attention problems. The kernel fuses mask + softmax + weighted sum for a
-TILE of targets per program, keeping the (TILE, H, K) score block in VMEM
+TILE of targets per program, keeping the (TILE, K) score block in VMEM
 (the jnp path round-trips scores and normalized weights through HBM).
 
-Layout: q (N, H, Dh); k/v (N, K, H, Dh); mask (N, K). N is padded to a
-multiple of TILE by ops.py.
+Layout: q (N, H*Dh); k/v (N, K, H*Dh) with each head's Dh lanes
+contiguous; mask (N, K). N is padded to a multiple of TILE by ops.py.
+A per-target (1 x Dh) @ (Dh x K) product is far too small for the MXU
+(and Mosaic does not lower the 4-D batched einsum), so scores and the
+weighted sum are lane-masked multiply-reduce passes on the VPU, one per
+head.
 """
 from __future__ import annotations
 
@@ -17,53 +21,44 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _kernel(q_ref, k_ref, v_ref, m_ref, o_ref, *, tile: int):
-    q = q_ref[...]                    # (T, H, Dh)
-    k = k_ref[...]                    # (T, K, H, Dh)
-    v = v_ref[...]
-    m = m_ref[...] != 0               # (T, K)
-    dh = q.shape[-1]
-    s = jnp.einsum("nhd,nkhd->nhk", q, k,
-                   preferred_element_type=jnp.float32) * (dh ** -0.5)
-    s = jnp.where(m[:, None, :], s, -1e30)
-    smax = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - smax)
-    p = jnp.where(m[:, None, :], p, 0.0)
-    denom = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    a = (p / denom).astype(v.dtype)
-    o_ref[...] = jnp.einsum("nhk,nkhd->nhd", a, v,
-                            preferred_element_type=jnp.float32
-                            ).astype(o_ref.dtype)
+from repro.kernels.platform import pallas_call
 
 
-def temporal_attn_kernel(q, k, v, mask, *, tile: int = 8,
-                         interpret: bool = True):
-    N, H, Dh = q.shape
+def _kernel(q_ref, k_ref, v_ref, m_ref, o_ref, *, n_heads: int, dh: int):
+    q = q_ref[...].astype(jnp.float32)      # (T, H*Dh)
+    k = k_ref[...].astype(jnp.float32)      # (T, K, H*Dh)
+    v = v_ref[...].astype(jnp.float32)
+    m = m_ref[...] != 0                     # (T, K)
+    lane = jax.lax.broadcasted_iota(jnp.int32, k.shape, 2)
+    qk = q[:, None, :] * k
+    out = jnp.zeros(q.shape, jnp.float32)
+    for h in range(n_heads):
+        in_head = (lane >= h * dh) & (lane < (h + 1) * dh)
+        s = jnp.sum(jnp.where(in_head, qk, 0.0), axis=-1) * (dh ** -0.5)
+        s = jnp.where(m, s, -1e30)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(m, p, 0.0)
+        a = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        out = out + jnp.sum(jnp.where(in_head, a[:, :, None] * v, 0.0),
+                            axis=1)
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def temporal_attn_kernel(q, k, v, mask, *, n_heads: int, tile: int = 8):
+    """q: (N, H*Dh); k, v: (N, K, H*Dh); mask: (N, K) -> (N, H*Dh)."""
+    N, HD = q.shape
     K = k.shape[1]
     assert N % tile == 0, "caller pads N to a tile multiple"
-    grid = (N // tile,)
-
-    def tmap(i):
-        return (i, 0, 0)
-
-    def tmap4(i):
-        return (i, 0, 0, 0)
-
-    def mmap(i):
-        return (i, 0)
-
-    fn = pl.pallas_call(
-        functools.partial(_kernel, tile=tile),
-        grid=grid,
+    fn = pallas_call(
+        functools.partial(_kernel, n_heads=n_heads, dh=HD // n_heads),
+        grid=(N // tile,),
         in_specs=[
-            pl.BlockSpec((tile, H, Dh), tmap),
-            pl.BlockSpec((tile, K, H, Dh), tmap4),
-            pl.BlockSpec((tile, K, H, Dh), tmap4),
-            pl.BlockSpec((tile, K), mmap),
+            pl.BlockSpec((tile, HD), lambda i: (i, 0)),
+            pl.BlockSpec((tile, K, HD), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tile, K, HD), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tile, K), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tile, H, Dh), tmap),
-        out_shape=jax.ShapeDtypeStruct((N, H, Dh), q.dtype),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((tile, HD), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, HD), q.dtype),
     )
     return fn(q, k, v, mask.astype(jnp.int32))

@@ -12,13 +12,14 @@ from repro.kernels.temporal_sample.temporal_sample import (
     NULL, temporal_sample_kernel)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "policy", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "policy"))
 def temporal_sample_pallas(page_table_rows, page_tmin, page_tmax,
                            pages_nbr, pages_eid, pages_ts, pages_valid,
                            targets, t_end, t_start, tmask, *, k: int,
-                           policy: str = "recent", rng_key=None,
-                           interpret: bool = True):
-    """Gathers each target's page-table row then invokes the kernel.
+                           policy: str = "recent", rng_key=None):
+    """Gathers each target's page-table row, drops the pages whose
+    t_min/t_max descriptor misses the target's window (the paper's block
+    skip), then invokes the kernel.
 
     page_table_rows: (N_nodes, S) — full table; targets: (N,). For
     policy="uniform", ``rng_key`` drives the per-candidate Gumbel noise.
@@ -26,21 +27,17 @@ def temporal_sample_pallas(page_table_rows, page_tmin, page_tmax,
     """
     in_range = (targets >= 0) & (targets < page_table_rows.shape[0])
     safe_t = jnp.clip(targets, 0, page_table_rows.shape[0] - 1)
-    pt = jnp.where((tmask & in_range)[:, None],
-                   page_table_rows[safe_t], NULL).astype(jnp.int32)
-    tq = jnp.stack([t_start, t_end], axis=1).astype(jnp.float32)
+    pt = page_table_rows[safe_t].astype(jnp.int32)
+    pid = jnp.clip(pt, 0, page_tmin.shape[0] - 1)
+    hit = ((pt != NULL) & (tmask & in_range)[:, None]
+           & (page_tmin[pid] < t_end[:, None])
+           & (page_tmax[pid] >= t_start[:, None]))
+    pt = jnp.where(hit, pt, NULL)
     noise = None
     if policy == "uniform":
-        assert rng_key is not None, "uniform policy needs an rng key"
-        N, S = pt.shape
-        C = pages_ts.shape[1]
-        noise = gumbel_noise(rng_key, (N, S, C))
-    nbr, eid, ts, cnt = temporal_sample_kernel(
-        pt, page_tmin.astype(jnp.float32), page_tmax.astype(jnp.float32),
-        pages_nbr.astype(jnp.int32), pages_eid.astype(jnp.int32),
-        pages_ts.astype(jnp.float32), pages_valid, tq,
-        tmask, k=k, policy=policy, noise=noise, interpret=interpret)
-    # counters are broadcast along k; slot-validity = slot index < count
-    mask = jnp.arange(k)[None, :] < cnt[:, 0:1]
-    return (jnp.where(mask, nbr, NULL), jnp.where(mask, eid, NULL),
-            jnp.where(mask, ts, 0.0), mask)
+        if rng_key is None:
+            raise ValueError("uniform policy needs an rng key")
+        noise = gumbel_noise(rng_key, pt.shape + (pages_ts.shape[1],))
+    return temporal_sample_kernel(
+        pt, pages_nbr, pages_eid, pages_ts, pages_valid, t_start, t_end,
+        k=k, policy=policy, noise=noise)

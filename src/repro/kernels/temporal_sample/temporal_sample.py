@@ -3,24 +3,35 @@
 GNNFlow Algorithm 1, re-derived for the TPU (DESIGN.md §2):
   * the paper's warp-per-target traversal becomes one grid *program* per
     target; the page loop is the second (minor, sequential) grid dim, so
-    per-target state (fill count, output tile) lives in VMEM/SMEM scratch
-    across page steps — the same pattern as a flash-attention KV loop;
+    per-target state (the output tile) stays resident in VMEM across
+    page steps — the same pattern as a flash-attention KV loop;
   * the paper's per-thread binary search inside a block becomes a masked
-    VPU compare over the page's 128-lane timestamp vector (a lane-parallel
-    "search" is one vector op);
-  * the paper's register-cached 72-byte block descriptor becomes the
-    scalar-prefetched page id + t_min/t_max scalars (SMEM), which also
-    drive the BlockSpec index_map — pages whose window misses are still
-    DMA'd (block shapes are static) but skipped in compute, matching the
-    paper's "skip blocks outside the range" control flow at the memory
-    level available on TPU.
+    VPU compare over the page's lane vector (a lane-parallel "search"
+    is one vector op);
+  * the paper's register-cached block descriptor check (t_min/t_max
+    skip) runs in the wrapper as one XLA pass over the targets' page
+    table rows: a page whose window misses becomes a NULL entry, and
+    the scalar-prefetched page id then both drives the BlockSpec
+    index_map and skips the compute of NULL pages.
 
-Layout: pages_* are (P, C) with C = page_cap (lane-padded); lanes are
-oldest-first within a page, pages arrive newest-first via the page table.
+Layout: pages_* are viewed as (P, 1, C) so each DMA'd page row is a
+(1, C) block spanning the array's two minor dims (the TPU tiling rule);
+lanes are oldest-first within a page, pages arrive newest-first via
+the page table. SMEM holds 1 MiB on v5e and pads a 2-D array's minor
+dim to 128 words, so the page table and windows are prefetched
+flattened, for at most ``_SMEM_TABLE_ENTRIES // S`` targets per call;
+the wrapper loops over chunks of targets.
+
+Mosaic lowers neither cumsum, flip, top_k nor a 1-D gather, so each
+page step works on (C, C) and (C, K) masks instead: a lane's rank is a
+masked count over the other lanes, a lane value becomes a column by a
+masked reduce over the diagonal, and slot s of the output takes the
+one candidate whose rank is s.
 
 Policies:
-  * recent  — running fill of the newest-K in-window edges, with an
-    early-stop once the output tile is full (``_kernel_recent``);
+  * recent  — running fill of the newest-K in-window edges
+    (``_kernel_recent``); a candidate's slot is the number of filled
+    slots plus the number of newer in-window lanes of its page;
   * uniform — sampling without replacement via Gumbel top-k: i.i.d.
     Gumbel noise (supplied as an input so the kernel is deterministic
     and testable) scores every candidate, and the kernel keeps a
@@ -35,19 +46,42 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import pallas_call
 
 NULL = -1
+_SMEM_TABLE_ENTRIES = 1 << 16     # 256 KiB of int32 page ids per call
+_IMIN = jnp.iinfo(jnp.int32).min
 
 
-def _kernel_recent(page_ids_ref,     # scalar prefetch: (N, S) int32
-            tmin_ref, tmax_ref,      # scalar prefetch: (P,) f32
-            # inputs (blocked):
-            nbr_ref, eid_ref, ts_ref, val_ref,   # (1, C) page row
-            tq_ref,                  # (1, 2) [t_start, t_end] for target
-            msk_ref,                 # (1, 1) target mask
-            # outputs:
-            out_nbr_ref, out_eid_ref, out_ts_ref, out_cnt_ref,  # (1, K)
-            *, k: int, page_cap: int, scan_pages: int):
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _column(row, fill):
+    """(1, C) row -> (C, 1) column: a masked reduce over the diagonal."""
+    c = row.shape[1]
+    diag = _iota((c, c), 0) == _iota((c, c), 1)
+    return jnp.max(jnp.where(diag, jnp.broadcast_to(row, (c, c)), fill),
+                   axis=1, keepdims=True)
+
+
+def _count(mask):
+    return jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _page_window(i, tq_ref, ts_ref, val_ref):
+    """In-window mask (1, C) of the current page for target i."""
+    ts = ts_ref[...]
+    return ((val_ref[...] != 0) & (ts >= tq_ref[2 * i])
+            & (ts < tq_ref[2 * i + 1]))
+
+
+def _kernel_recent(pt_ref, tq_ref,          # scalar prefetch: (N*S,), (2N,)
+                   nbr_ref, eid_ref, ts_ref, val_ref,   # (1, C) page row
+                   out_nbr_ref, out_eid_ref, out_ts_ref,  # (1, K)
+                   *, k: int, s: int):
     i = pl.program_id(0)             # target index
     j = pl.program_id(1)             # page step (newest-first)
 
@@ -56,61 +90,37 @@ def _kernel_recent(page_ids_ref,     # scalar prefetch: (N, S) int32
         out_nbr_ref[...] = jnp.full((1, k), NULL, jnp.int32)
         out_eid_ref[...] = jnp.full((1, k), NULL, jnp.int32)
         out_ts_ref[...] = jnp.zeros((1, k), jnp.float32)
-        out_cnt_ref[...] = jnp.zeros((1, k), jnp.int32)
 
-    count = out_cnt_ref[0, 0]
-    t_start = tq_ref[0, 0]
-    t_end = tq_ref[0, 1]
-    pid = page_ids_ref[i, j]
-    alive = (pid != NULL) & (msk_ref[0, 0] != 0) & (count < k)
-    # block descriptor check (the paper's t_min/t_max skip)
-    pid_c = jnp.maximum(pid, 0)
-    hit = alive & (tmin_ref[pid_c] < t_end) & (tmax_ref[pid_c] >= t_start)
-
-    @pl.when(hit)
+    @pl.when(pt_ref[i * s + j] != NULL)
     def _scan_page():
-        ts_row = ts_ref[0, :]                      # (C,) oldest-first
-        val_row = val_ref[0, :] != 0
-        in_win = val_row & (ts_row >= t_start) & (ts_row < t_end)
-        # newest-first lane order (jnp.flip: Pallas refs reject step=-1)
-        rev = jnp.flip(in_win)
-        ts_rev = jnp.flip(ts_row)
-        nbr_rev = jnp.flip(nbr_ref[0, :])
-        eid_rev = jnp.flip(eid_ref[0, :])
-        # rank of each newest-first candidate in the global output
-        rank = count + jnp.cumsum(rev.astype(jnp.int32)) - 1
-        rank = jnp.where(rev, rank, -1)
-        # scatter into the K output slots via a (K, C) selection mask,
-        # reduced with max (exactly one lane per slot)
-        sel = rank[None, :] == jnp.arange(k, dtype=jnp.int32)[:, None]
-        pick = lambda row, fill: jnp.max(
-            jnp.where(sel, row[None, :], fill), axis=1)
-        new_nbr = pick(nbr_rev, NULL)
-        new_eid = pick(eid_rev, NULL)
-        new_ts = pick(ts_rev, -jnp.inf)
-        got = jnp.any(sel, axis=1)
-        out_nbr_ref[0, :] = jnp.where(got, new_nbr, out_nbr_ref[0, :])
-        out_eid_ref[0, :] = jnp.where(got, new_eid, out_eid_ref[0, :])
-        out_ts_ref[0, :] = jnp.where(got, new_ts.astype(jnp.float32),
-                                     out_ts_ref[0, :])
-        n_new = jnp.sum(rev.astype(jnp.int32))
-        out_cnt_ref[...] = jnp.minimum(count + n_new,
-                                       k).astype(jnp.int32)[None, None
-                                                            ] * jnp.ones(
-            (1, k), jnp.int32)
+        in_win = _page_window(i, tq_ref, ts_ref, val_ref)     # (1, C)
+        c = in_win.shape[1]
+        win_i = in_win.astype(jnp.int32)
+        # in-window lanes newer than lane r (lanes are oldest-first)
+        newer = jnp.sum(jnp.where(_iota((c, c), 1) > _iota((c, c), 0),
+                                  jnp.broadcast_to(win_i, (c, c)), 0),
+                        axis=1, keepdims=True)                 # (C, 1)
+        filled = _count(out_eid_ref[...] != NULL)              # (1, 1)
+        sel = ((_column(win_i, 0) != 0)
+               & (filled + newer == _iota((c, k), 1)))         # (C, K)
+        got = jnp.max(sel.astype(jnp.int32), axis=0, keepdims=True) != 0
+
+        def pick(ref, row, fill):
+            new = jnp.max(jnp.where(sel, _column(row, fill), fill),
+                          axis=0, keepdims=True)
+            ref[...] = jnp.where(got, new, ref[...])
+
+        pick(out_nbr_ref, nbr_ref[...], _IMIN)
+        pick(out_eid_ref, eid_ref[...], _IMIN)
+        pick(out_ts_ref, ts_ref[...], -jnp.inf)
 
 
-def _kernel_uniform(page_ids_ref,    # scalar prefetch: (N, S) int32
-                    tmin_ref, tmax_ref,      # scalar prefetch: (P,) f32
-                    # inputs (blocked):
-                    nbr_ref, eid_ref, ts_ref, val_ref,   # (1, C) page row
-                    noise_ref,               # (1, 1, C) Gumbel noise
-                    tq_ref,                  # (1, 2) [t_start, t_end]
-                    msk_ref,                 # (1, 1) target mask
-                    # outputs:
-                    out_nbr_ref, out_eid_ref, out_ts_ref, out_cnt_ref,
+def _kernel_uniform(pt_ref, tq_ref,         # scalar prefetch: (N*S,), (2N,)
+                    nbr_ref, eid_ref, ts_ref, val_ref,  # (1, C) page row
+                    noise_ref,               # (1, C) Gumbel noise
+                    out_nbr_ref, out_eid_ref, out_ts_ref,
                     out_score_ref,           # (1, K) running reservoir
-                    *, k: int, page_cap: int, scan_pages: int):
+                    *, k: int, s: int):
     i = pl.program_id(0)             # target index
     j = pl.program_id(1)             # page step (newest-first)
 
@@ -119,110 +129,101 @@ def _kernel_uniform(page_ids_ref,    # scalar prefetch: (N, S) int32
         out_nbr_ref[...] = jnp.full((1, k), NULL, jnp.int32)
         out_eid_ref[...] = jnp.full((1, k), NULL, jnp.int32)
         out_ts_ref[...] = jnp.zeros((1, k), jnp.float32)
-        out_cnt_ref[...] = jnp.zeros((1, k), jnp.int32)
         out_score_ref[...] = jnp.full((1, k), -jnp.inf, jnp.float32)
 
-    count = out_cnt_ref[0, 0]
-    t_start = tq_ref[0, 0]
-    t_end = tq_ref[0, 1]
-    pid = page_ids_ref[i, j]
-    # no early-stop: unlike recent, every candidate must get a chance
-    alive = (pid != NULL) & (msk_ref[0, 0] != 0)
-    pid_c = jnp.maximum(pid, 0)
-    hit = alive & (tmin_ref[pid_c] < t_end) & (tmax_ref[pid_c] >= t_start)
-
-    @pl.when(hit)
+    # no early stop: every candidate must get a chance
+    @pl.when(pt_ref[i * s + j] != NULL)
     def _merge_page():
-        ts_row = ts_ref[0, :]                      # (C,)
-        val_row = val_ref[0, :] != 0
-        in_win = val_row & (ts_row >= t_start) & (ts_row < t_end)
-        cand_score = jnp.where(in_win, noise_ref[0, 0, :], -jnp.inf)
-        # merge the page's candidates into the running top-k reservoir
-        comb_score = jnp.concatenate([out_score_ref[0, :], cand_score])
-        comb_nbr = jnp.concatenate([out_nbr_ref[0, :], nbr_ref[0, :]])
-        comb_eid = jnp.concatenate([out_eid_ref[0, :], eid_ref[0, :]])
-        comb_ts = jnp.concatenate([out_ts_ref[0, :], ts_row])
-        top_s, top_i = jax.lax.top_k(comb_score, k)
-        out_score_ref[0, :] = top_s
-        out_nbr_ref[0, :] = comb_nbr[top_i]
-        out_eid_ref[0, :] = comb_eid[top_i]
-        out_ts_ref[0, :] = comb_ts[top_i].astype(jnp.float32)
-        n_new = jnp.sum(in_win.astype(jnp.int32))
-        out_cnt_ref[...] = jnp.minimum(count + n_new,
-                                       k).astype(jnp.int32)[None, None
-                                                            ] * jnp.ones(
-            (1, k), jnp.int32)
+        in_win = _page_window(i, tq_ref, ts_ref, val_ref)
+        c = in_win.shape[1]
+        # top-k of [reservoir | page] in score order, ties to the lower
+        # index (reservoir first) as lax.top_k breaks them: each
+        # element's rank counts the elements ordered before it
+        sc = jnp.where(in_win, noise_ref[...], -jnp.inf)       # (1, C)
+        rs = out_score_ref[...]                                # (1, K)
+        sc_col = _column(sc, -jnp.inf)                         # (C, 1)
+        rs_col = _column(rs, -jnp.inf)                         # (K, 1)
+        before_cc = (sc > sc_col) | ((sc == sc_col)
+                                     & (_iota((c, c), 1)
+                                        < _iota((c, c), 0)))
+        before_kk = (rs > rs_col) | ((rs == rs_col)
+                                     & (_iota((k, k), 1)
+                                        < _iota((k, k), 0)))
+        rank_page = _count(before_cc) + _count(rs >= sc_col)   # (C, 1)
+        rank_res = _count(before_kk) + _count(sc > rs_col)     # (K, 1)
+        sel_p = rank_page == _iota((c, k), 1)                  # (C, K)
+        sel_r = rank_res == _iota((k, k), 1)                   # (K, K)
+
+        def merge(ref, page_row, fill):
+            res_row = ref[...]
+            new = jnp.maximum(
+                jnp.max(jnp.where(sel_p, _column(page_row, fill), fill),
+                        axis=0, keepdims=True),
+                jnp.max(jnp.where(sel_r, _column(res_row, fill), fill),
+                        axis=0, keepdims=True))
+            ref[...] = new
+
+        merge(out_nbr_ref, nbr_ref[...], _IMIN)
+        merge(out_eid_ref, eid_ref[...], _IMIN)
+        merge(out_ts_ref, ts_ref[...], -jnp.inf)
+        merge(out_score_ref, sc, -jnp.inf)
 
 
-def temporal_sample_kernel(page_table, page_tmin, page_tmax, pages_nbr,
-                           pages_eid, pages_ts, pages_valid, t_query,
-                           tmask, *, k: int, policy: str = "recent",
-                           noise=None, interpret: bool = True):
-    """page_table: (N, S) newest-first page ids; pages_*: (P, C);
-    t_query: (N, 2) [t_start, t_end]; tmask: (N,) int32; noise: (N, S, C)
-    Gumbel scores, required for policy="uniform".
-    Returns (nbr, eid, ts, cnt) each (N, k) / cnt (N, k) fill counters."""
+def _sample_chunk(page_table, tq, pages, noise, *, k: int, policy: str):
     N, S = page_table.shape
-    P, C = pages_ts.shape
-    grid = (N, S)
-
-    def page_map(i, j, page_ids, tmin, tmax):
-        return (jnp.maximum(page_ids[i, j], 0), 0)
-
-    def noise_map(i, j, *_):
-        return (i, j, 0)
-
-    def tq_map(i, j, *_):
-        return (i, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, C), page_map),   # nbr
-        pl.BlockSpec((1, C), page_map),   # eid
-        pl.BlockSpec((1, C), page_map),   # ts
-        pl.BlockSpec((1, C), page_map),   # valid
-    ]
-    out_specs = [
-        pl.BlockSpec((1, k), tq_map),
-        pl.BlockSpec((1, k), tq_map),
-        pl.BlockSpec((1, k), tq_map),
-        pl.BlockSpec((1, k), tq_map),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((N, k), jnp.int32),
-        jax.ShapeDtypeStruct((N, k), jnp.int32),
-        jax.ShapeDtypeStruct((N, k), jnp.float32),
-        jax.ShapeDtypeStruct((N, k), jnp.int32),
-    ]
-    inputs = [pages_nbr, pages_eid, pages_ts,
-              pages_valid.astype(jnp.int32)]
+    C = pages[0].shape[2]
+    page_row = pl.BlockSpec(
+        (pl.Squeezed(), 1, C),
+        lambda i, j, pt, tq_: (jnp.maximum(pt[i * S + j], 0), 0, 0))
+    out_row = pl.BlockSpec((pl.Squeezed(), 1, k),
+                           lambda i, j, *_: (i, 0, 0))
+    in_specs = [page_row] * 4
+    inputs = list(pages)
+    n_out = 3
     if policy == "uniform":
-        assert noise is not None, "uniform policy needs Gumbel noise"
-        in_specs.append(pl.BlockSpec((1, 1, C), noise_map))
-        inputs.append(noise.astype(jnp.float32))
-        out_specs.append(pl.BlockSpec((1, k), tq_map))
-        out_shape.append(jax.ShapeDtypeStruct((N, k), jnp.float32))
+        in_specs.append(pl.BlockSpec((pl.Squeezed(), 1, C),
+                                     lambda i, j, *_: (i * S + j, 0, 0)))
+        inputs.append(noise.reshape(N * S, 1, C))
+        n_out = 4
         body = _kernel_uniform
     else:
-        assert policy == "recent", policy
         body = _kernel_recent
-    in_specs += [
-        pl.BlockSpec((1, 2), tq_map),     # t_query
-        pl.BlockSpec((1, 1), tq_map),     # tmask
-    ]
-    kern = functools.partial(body, k=k, page_cap=C, scan_pages=S)
-    fn = pl.pallas_call(
-        kern,
-        grid_spec=pltpu_prefetch(grid, in_specs, out_specs, n_prefetch=3),
-        out_shape=out_shape,
-        interpret=interpret,
+    dtypes = (jnp.int32, jnp.int32, jnp.float32, jnp.float32)[:n_out]
+    fn = pallas_call(
+        functools.partial(body, k=k, s=S),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(N, S), in_specs=in_specs,
+            out_specs=[out_row] * n_out),
+        out_shape=[jax.ShapeDtypeStruct((N, 1, k), d) for d in dtypes],
     )
-    out = fn(page_table, page_tmin, page_tmax, *inputs, t_query,
-             tmask.astype(jnp.int32).reshape(N, 1))
-    return out[:4]
+    return [o.reshape(N, k)
+            for o in fn(page_table.reshape(-1), tq.reshape(-1), *inputs)]
 
 
-def pltpu_prefetch(grid, in_specs, out_specs, n_prefetch):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch, grid=grid, in_specs=in_specs,
-        out_specs=out_specs)
+def temporal_sample_kernel(page_table, pages_nbr, pages_eid, pages_ts,
+                           pages_valid, t_start, t_end, *, k: int,
+                           policy: str = "recent", noise=None):
+    """page_table: (N, S) newest-first page ids, NULL for pages to skip;
+    pages_*: (P, C); t_start/t_end: (N,) window; noise: (N, S, C)
+    Gumbel scores, required for policy="uniform".
+    Returns (nbr, eid, ts, mask) each (N, k)."""
+    if policy not in ("recent", "uniform"):
+        raise ValueError(f"unknown policy {policy!r}")
+    if policy == "uniform" and noise is None:
+        raise ValueError("uniform policy needs Gumbel noise")
+    N, S = page_table.shape
+    P, C = pages_ts.shape
+    pages = [pages_nbr.astype(jnp.int32), pages_eid.astype(jnp.int32),
+             pages_ts.astype(jnp.float32),
+             pages_valid.astype(jnp.int32)]
+    pages = [a.reshape(P, 1, C) for a in pages]
+    tq = jnp.stack([t_start, t_end], axis=1).astype(jnp.float32)
+    step = max(8, _SMEM_TABLE_ENTRIES // S)
+    outs = [_sample_chunk(
+        page_table[lo:lo + step], tq[lo:lo + step], pages,
+        None if noise is None else noise[lo:lo + step].astype(jnp.float32),
+        k=k, policy=policy) for lo in range(0, max(N, 1), step)]
+    nbr, eid, ts, *score = [jnp.concatenate(o) for o in zip(*outs)]
+    mask = (score[0] > -jnp.inf) if score else (eid != NULL)
+    return (jnp.where(mask, nbr, NULL), jnp.where(mask, eid, NULL),
+            jnp.where(mask, ts, 0.0), mask)

@@ -26,7 +26,7 @@ import numpy as np
 
 from repro.configs.base import MoEConfig
 from repro.dist.sharding import (active_mesh, axis_for, axis_size_of,
-                                 constrain, shard_map)
+                                 constrain)
 from repro.models.layers import dense_init, mlp_apply
 
 
@@ -282,7 +282,7 @@ def _moe_apply_ep(params: dict, x: jnp.ndarray, moe: MoEConfig, act: str
         else:
             pspecs[name] = pspec(name, leaf)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p, xx: _moe_local_shard(p, xx, moe, act, ep_names,
                                        all_names),
         mesh=mesh, in_specs=(pspecs, x_spec),

@@ -162,7 +162,7 @@ def _cp_attention_shard_map(q, k, v, *, causal: bool,
     instead of (S_loc x S) f32 tensors).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.dist.sharding import active_mesh, axis_for, shard_map
+    from repro.dist.sharding import active_mesh, axis_for
 
     mesh = active_mesh()
     dp_ax = axis_for("batch")
@@ -183,8 +183,8 @@ def _cp_attention_shard_map(q, k, v, *, causal: bool,
                                 q_offset=offset)
 
     spec = P(dp_ax, sp_ax, None, None)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def attn_full(p: dict, x: jnp.ndarray, cfg: ArchConfig,

@@ -417,9 +417,11 @@ class QueryEngine:
     # -- offline replay (parity harnesses) -------------------------------
     def offline_forward(self, version: int, src, dst=None, ts=None):
         """Recompute a query on the RETAINED handle for ``version`` —
-        the parity oracle: a served response must match this ≤ 1e-4.
-        Bypasses admission, batching and the caches; safe from any
-        thread."""
+        the parity oracle: a served response must match this ≤ 1e-4
+        at float32 matmul precision (a TPU at its default precision
+        rounds matmul operands to bf16, and the batched and the replayed
+        program may round an intermediate differently). Bypasses
+        admission, batching and the caches; safe from any thread."""
         handle = self.publisher.get(version)
         if handle is None:
             raise KeyError(f"version {version} not in publisher history")

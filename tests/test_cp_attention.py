@@ -7,13 +7,14 @@ _SCRIPT = '''
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.dist.sharding import ShardingRules, sharding_ctx
 from repro.models.layers import blocked_attention
 from repro.models.transformer_lm import _cp_attention_shard_map
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = ShardingRules({"batch": ("data",), "seq_act": "model"})
 
 B, S, Hq, Hkv, D = 4, 64, 8, 4, 16

@@ -12,18 +12,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.dist.collectives import (bucketed_psum, quantized_psum_grads,
                                     topk_psum_grads)
-from repro.dist.sharding import shard_map
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 (fake) devices")
 
 
 def _mesh8():
-    return jax.make_mesh((8,), ("data",))
+    return jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 
 
 def _grads(seed=0):
@@ -38,7 +37,7 @@ def test_bucketed_psum_matches_plain_psum_exactly():
     mesh = _mesh8()
     g = _grads()
     got = bucketed_psum(g, mesh, bucket_bytes=2048)
-    plain = shard_map(
+    plain = jax.shard_map(
         lambda t: jax.tree.map(lambda x: lax.psum(x, ("data",)), t),
         mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False)(g)
     for k_got, k_plain in zip(jax.tree.leaves(got), jax.tree.leaves(plain)):
@@ -58,8 +57,8 @@ def test_bucketed_psum_distinct_shards_sum():
         red = bucketed_psum({"w": shard[0]}, ("data",), bucket_bytes=128)
         return red["w"][None]
 
-    out = shard_map(body, mesh=mesh, in_specs=(P("data", None),),
-                    out_specs=P("data", None), check_vma=False)(g_all)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(P("data", None),),
+                        out_specs=P("data", None), check_vma=False)(g_all)
     expect = np.asarray(g_all).sum(axis=0)
     for row in np.asarray(out):
         np.testing.assert_allclose(row, expect, rtol=1e-5, atol=1e-5)

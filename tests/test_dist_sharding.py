@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.dist import sharding as sh
 
@@ -12,7 +12,8 @@ needs8 = pytest.mark.skipif(len(jax.devices()) < 8,
 
 
 def _mesh24():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_arch
 from repro.dist.sharding import (default_rules, named_shardings,
@@ -19,7 +19,8 @@ from repro.dist.sharding import (default_rules, named_shardings,
 from repro.launch import hlo_cost
 from repro.models import lm_zoo
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 out = {}
 for arch in ("yi-6b", "qwen3-moe-235b-a22b", "falcon-mamba-7b",
              "zamba2-2.7b", "hubert-xlarge"):

@@ -10,13 +10,14 @@ _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import MoEConfig
 from repro.dist.sharding import ShardingRules, sharding_ctx
 from repro.models.moe import _moe_apply_dense, moe_apply, moe_init
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = ShardingRules({
     "batch": ("data",), "seq_act": "model", "expert": "model",
     "fsdp": None, "embed_fsdp": None, "moe_fsdp": None, "tp": None,

@@ -3,6 +3,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -159,7 +160,7 @@ def test_straggler_policy():
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
 
 
 def test_quantized_psum_error_feedback():
