@@ -49,7 +49,7 @@ from repro.data.events import EventStream
 from repro.data.loader import (chronological_batches, replay_mix,
                                sample_negatives)
 from repro.models import gnn as G
-from repro.obs import trace
+from repro.obs import trace, xfer
 from repro.obs.metrics import MetricRegistry
 from repro.train.optimizer import Optimizer, adamw
 
@@ -143,15 +143,15 @@ class TGNMemory:
         other = np.where(has, self.raw_other[safe], 0)
         eid = np.where(has, self.raw_eid[safe], 0)
         t = np.where(has, self.raw_t[safe], 0.0)
-        mem, last_upd = self.state.get_memory(ids)
-        other_mem, _ = self.state.get_memory(other)
+        mem, last_upd = xfer.gather(self.state.get_memory, ids)
+        other_mem, _ = xfer.gather(self.state.get_memory, other)
         return {
-            "mem": jnp.asarray(mem),
-            "last_upd": jnp.asarray(last_upd, jnp.float32),
-            "other_mem": jnp.asarray(other_mem),
-            "e_feat": jnp.asarray(edge_feat_fn(eid)),
-            "msg_t": jnp.asarray(t, jnp.float32),
-            "has": jnp.asarray(has),
+            "mem": xfer.h2d(mem),
+            "last_upd": xfer.h2d(last_upd, jnp.float32),
+            "other_mem": xfer.h2d(other_mem),
+            "e_feat": xfer.h2d(xfer.gather(edge_feat_fn, eid)),
+            "msg_t": xfer.h2d(t, jnp.float32),
+            "has": xfer.h2d(has),
         }
 
     def commit_and_stage(self, mem_params, src, dst, ts, eids,
@@ -166,30 +166,34 @@ class TGNMemory:
         t's values into the shared shard.  The pending set derives from
         replicated host state, so all SPMD processes take the same
         branch and the fence (a fleet barrier) stays aligned."""
-        nodes = np.concatenate([src, dst])
-        others = np.concatenate([dst, src])
-        tts = np.concatenate([ts, ts])
-        ee = np.concatenate([eids, eids])
-        self._ensure(int(nodes.max(initial=0)) + 1)
+        with trace.span("memory.commit") as sp:
+            nodes = np.concatenate([src, dst])
+            others = np.concatenate([dst, src])
+            tts = np.concatenate([ts, ts])
+            ee = np.concatenate([eids, eids])
+            self._ensure(int(nodes.max(initial=0)) + 1)
 
-        uniq = np.unique(nodes)
-        pend = uniq[self.raw_has[uniq]]
-        if len(pend):
-            g = self.gather(pend, edge_feat_fn)
-            new_mem = G.memory_batch_update(
-                mem_params, jnp.asarray(pend), g["mem"], g["last_upd"],
-                g["other_mem"], g["e_feat"], g["msg_t"])
-            new_mem = np.asarray(new_mem)
-            if fence is not None:
-                fence()     # all peers done reading the old memory
-            self.state.put_memory(pend, new_mem, self.raw_t[pend])
-            self.raw_has[pend] = False
-        # stage new messages, last event per node wins ('last' aggregator;
-        # events are time-sorted so later assignment overwrites earlier)
-        self.raw_other[nodes] = others
-        self.raw_eid[nodes] = ee
-        self.raw_t[nodes] = tts
-        self.raw_has[nodes] = True
+            uniq = np.unique(nodes)
+            pend = uniq[self.raw_has[uniq]]
+            if len(pend):
+                g = self.gather(pend, edge_feat_fn)
+                new_mem = G.memory_batch_update(
+                    mem_params, xfer.h2d(pend), g["mem"], g["last_upd"],
+                    g["other_mem"], g["e_feat"], g["msg_t"])
+                new_mem = xfer.d2h(new_mem)
+                if fence is not None:
+                    fence()     # all peers done reading the old memory
+                self.state.put_memory(pend, new_mem, self.raw_t[pend])
+                self.raw_has[pend] = False
+            # stage new messages, last event per node wins ('last'
+            # aggregator; events are time-sorted so later assignment
+            # overwrites earlier)
+            self.raw_other[nodes] = others
+            self.raw_eid[nodes] = ee
+            self.raw_t[nodes] = tts
+            self.raw_has[nodes] = True
+            if trace.enabled():
+                sp.set(nodes=len(pend))
 
 
 # ---------------------------------------------------------------------------

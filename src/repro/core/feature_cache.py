@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import xfer
 from repro.obs.metrics import MetricRegistry
 
 NULL = -1
@@ -214,8 +215,8 @@ class FeatureCache:
 
     def update(self, ids, hit, miss_feats) -> None:
         self.state = cache_update(
-            self.state, jnp.asarray(ids, jnp.int32), hit,
-            jnp.asarray(miss_feats), policy=self.policy,
+            self.state, xfer.h2d(ids, jnp.int32), hit,
+            xfer.h2d(miss_feats), policy=self.policy,
             max_replace=self.max_replace)
 
     def invalidate(self, ids) -> int:
@@ -231,16 +232,16 @@ class FeatureCache:
         if not present.any():
             return 0
         hot = np.unique(np.asarray(ids, np.int64)[present])
-        slot_of = np.asarray(self.state.slot_of).copy()
-        sids = np.asarray(self.state.ids).copy()
-        score = np.asarray(self.state.score).copy()
+        slot_of = xfer.d2h(self.state.slot_of).copy()
+        sids = xfer.d2h(self.state.ids).copy()
+        score = xfer.d2h(self.state.score).copy()
         slots = slot_of[hot]
         sids[slots] = NULL
         score[slots] = _NEG
         slot_of[hot] = NULL
         self.state = dataclasses.replace(
-            self.state, slot_of=jnp.asarray(slot_of),
-            ids=jnp.asarray(sids), score=jnp.asarray(score))
+            self.state, slot_of=xfer.h2d(slot_of),
+            ids=xfer.h2d(sids), score=xfer.h2d(score))
         self._c_invalidated.add(len(hot))
         return len(hot)
 
@@ -250,8 +251,8 @@ class FeatureCache:
         the prefetcher uses it to skip rows the device cache will hit
         anyway."""
         ids = np.asarray(ids, np.int64)
-        slot_of = np.asarray(self.state.slot_of)
-        sids = np.asarray(self.state.ids)
+        slot_of = xfer.d2h(self.state.slot_of)
+        sids = xfer.d2h(self.state.ids)
         safe = np.clip(ids, 0, len(slot_of) - 1)
         slot = slot_of[safe]
         ok = (ids >= 0) & (ids < len(slot_of)) & (slot >= 0)
@@ -285,9 +286,9 @@ class FeatureCache:
             ok[:n] = np.asarray(cacheable, bool)
         else:
             ok = None
-        ids_j = jnp.asarray(ids_pad)
+        ids_j = xfer.h2d(ids_pad)
         feats, hit = self._lookup_raw(ids_j)
-        hit_np = np.asarray(hit)
+        hit_np = xfer.d2h(hit)
         counted = (ids_pad >= 0) if ok is None else ok
         self._c_accesses.add(int(counted.sum()))
         self._c_hits.add(int(hit_np[counted].sum()))
@@ -296,8 +297,8 @@ class FeatureCache:
         need = (~hit_np) & (ids_pad >= 0)
         miss_feats = np.zeros((bucket, self.dim), np.float32)
         if need.any():
-            miss_feats[need] = fetch_missing(ids_pad[need])
-        out = jnp.where(hit[:, None], feats, jnp.asarray(miss_feats))
+            miss_feats[need] = xfer.gather(fetch_missing, ids_pad[need])
+        out = jnp.where(hit[:, None], feats, xfer.h2d(miss_feats))
         if ok is None:
             ins_mask = need
             self.update(ids_j, hit, miss_feats)
@@ -305,7 +306,7 @@ class FeatureCache:
             # non-cacheable lanes become NULL so the update never
             # spends a slot (or an eviction) on them
             ins_mask = need & ok
-            upd_ids = jnp.asarray(np.where(ok, ids_pad, NULL))
+            upd_ids = xfer.h2d(np.where(ok, ids_pad, NULL))
             self.update(upd_ids, hit, miss_feats)
         if ins_mask.any():
             # insertion count computed host-side (distinct misses capped

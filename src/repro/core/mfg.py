@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sampling import SampledLayer
+from repro.obs import xfer
 
 
 def assemble(layers: List[SampledLayer],
@@ -25,32 +26,32 @@ def assemble(layers: List[SampledLayer],
     """Returns hops[l] dicts for repro.models.gnn.gnn_embed."""
     hops = []
     for layer in layers:
-        dst_ids = np.asarray(layer.dst_nodes, np.int64)
-        nbr_ids = np.asarray(layer.nbr_ids, np.int64)
-        eids = np.asarray(layer.nbr_eids, np.int64)
+        dst_ids = xfer.d2h(layer.dst_nodes, np.int64)
+        nbr_ids = xfer.d2h(layer.nbr_ids, np.int64)
+        eids = xfer.d2h(layer.nbr_eids, np.int64)
         N, K = nbr_ids.shape
 
-        dst_feat = np.asarray(fetch_node(dst_ids))
-        nbr_feat = np.asarray(fetch_node(nbr_ids.reshape(-1))) \
+        dst_feat = xfer.d2h(fetch_node(dst_ids))
+        nbr_feat = xfer.d2h(fetch_node(nbr_ids.reshape(-1))) \
             .reshape(N, K, -1)
-        edge_feat = np.asarray(fetch_edge(eids.reshape(-1))) \
+        edge_feat = xfer.d2h(fetch_edge(eids.reshape(-1))) \
             .reshape(N, K, -1)
         if fetch_memory is not None:
-            dst_mem = np.asarray(fetch_memory(dst_ids))
-            nbr_mem = np.asarray(
+            dst_mem = xfer.d2h(fetch_memory(dst_ids))
+            nbr_mem = xfer.d2h(
                 fetch_memory(nbr_ids.reshape(-1))).reshape(N, K, -1)
             dst_feat = np.concatenate([dst_feat, dst_mem], axis=-1)
             nbr_feat = np.concatenate([nbr_feat, nbr_mem], axis=-1)
 
-        dt = (np.asarray(layer.dst_times)[:, None]
-              - np.asarray(layer.nbr_ts))
-        dt = np.where(np.asarray(layer.mask), np.maximum(dt, 0.0), 0.0)
+        mask = xfer.d2h(layer.mask)
+        dt = xfer.d2h(layer.dst_times)[:, None] - xfer.d2h(layer.nbr_ts)
+        dt = np.where(mask, np.maximum(dt, 0.0), 0.0)
 
         hops.append({
-            "dst_feat": jnp.asarray(dst_feat, jnp.float32),
-            "nbr_feat": jnp.asarray(nbr_feat, jnp.float32),
-            "edge_feat": jnp.asarray(edge_feat, jnp.float32),
-            "dt": jnp.asarray(dt, jnp.float32),
-            "mask": jnp.asarray(np.asarray(layer.mask)),
+            "dst_feat": xfer.h2d(dst_feat, jnp.float32),
+            "nbr_feat": xfer.h2d(nbr_feat, jnp.float32),
+            "edge_feat": xfer.h2d(edge_feat, jnp.float32),
+            "dt": xfer.h2d(dt, jnp.float32),
+            "mask": xfer.h2d(mask),
         })
     return hops

@@ -20,10 +20,17 @@ Design goals, in order:
    offset-corrected (barrier exit == t=0). ``merge_chrome`` concatenates
    worker files onto distinct pids and rebases the fleet minimum to 0.
 
+5. **Causes and pauses.** Every span records as ``parent`` the kind of
+   the innermost span open on the same thread when it started (a
+   per-thread stack kept only while tracing is on), and ``enable()``
+   installs a ``gc.callbacks`` hook that records each collection of
+   generation 1 or 2 as a ``gc`` span on the thread that ran it.
+
 Enable via ``REPRO_TRACE=1`` in the environment or ``trace.enable()``.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -41,18 +48,23 @@ __all__ = [
     "begin_async",
     "end_async",
     "events",
+    "dropped",
     "export_chrome",
     "merge_chrome",
     "load_trace",
 ]
 
 TRACE_ENV = "REPRO_TRACE"
-DEFAULT_CAPACITY = 65536
+# a serving window records ~50 spans per admitted batch on the worker
+# thread, ~1,200 batches in 40 s: the default leaves room for 4x that
+DEFAULT_CAPACITY = 1 << 18
 
 _enabled: bool = False
 _capacity: int = DEFAULT_CAPACITY
 
-_reg_lock = threading.Lock()
+# re-entrant: the gc callback may need it on a thread that already holds
+# it (a collection can start inside any allocation)
+_reg_lock = threading.RLock()
 _buffers: List["_RingBuffer"] = []
 _tls = threading.local()
 
@@ -95,6 +107,43 @@ def _buffer() -> _RingBuffer:
     return buf
 
 
+def _stack() -> List[str]:
+    """Kinds of the spans open on this thread, innermost last."""
+    st = getattr(_tls, "stack", None)
+    if st is None:
+        st = _tls.stack = []
+    return st
+
+
+def _with_parent(args: Optional[Dict[str, Any]], parent: Optional[str]
+                 ) -> Optional[Dict[str, Any]]:
+    if parent is None:
+        return args
+    if args is None:
+        return {"parent": parent}
+    args["parent"] = parent
+    return args
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """``gc.callbacks`` hook: one ``gc`` span per collection of
+    generation 1 or 2, on the thread that ran it."""
+    if info["generation"] < 1:
+        return
+    if phase == "start":
+        _tls.gc_t0 = now_us()
+        return
+    t0 = getattr(_tls, "gc_t0", None)
+    _tls.gc_t0 = None
+    if t0 is None or not _enabled:
+        return
+    st = getattr(_tls, "stack", None)
+    args = _with_parent({"generation": info["generation"],
+                         "collected": info["collected"]},
+                        st[-1] if st else None)
+    _buffer().add("gc", t0, max(now_us() - t0, 0), None, args)
+
+
 def now_us() -> int:
     """Monotonic microseconds; the time base for every recorded event."""
     return time.perf_counter_ns() // 1000
@@ -115,12 +164,16 @@ def enable(capacity: int = DEFAULT_CAPACITY) -> None:
             if buf.cap != _capacity:
                 items = buf.snapshot()[-_capacity:]
                 buf.items, buf.idx, buf.cap = items, 0, _capacity
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
     _enabled = True
 
 
 def disable() -> None:
     global _enabled
     _enabled = False
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def reset() -> None:
@@ -151,19 +204,24 @@ _NOOP = _Noop()
 
 
 class _Span:
-    __slots__ = ("kind", "args", "t0")
+    __slots__ = ("kind", "args", "t0", "parent")
 
     def __init__(self, kind: str, args: Optional[Dict[str, Any]]) -> None:
         self.kind = kind
         self.args = args
 
     def __enter__(self) -> "_Span":
+        st = _stack()
+        self.parent = st[-1] if st else None
+        st.append(self.kind)
         self.t0 = now_us()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         t1 = now_us()
-        _buffer().add(self.kind, self.t0, max(t1 - self.t0, 0), None, self.args)
+        _tls.stack.pop()
+        _buffer().add(self.kind, self.t0, max(t1 - self.t0, 0), None,
+                      _with_parent(self.args, self.parent))
         return None
 
     def set(self, **kw: Any) -> None:
@@ -188,9 +246,10 @@ def span(kind: str, **args: Any):
 class _Stage:
     """Times a block into ``timers[key]`` AND emits a span over the same
     interval, so the metric registry and the trace agree by construction.
-    Timing happens regardless of whether tracing is enabled."""
+    Timing happens regardless of whether tracing is enabled; the span is
+    recorded when tracing was on as the block began."""
 
-    __slots__ = ("timers", "key", "args", "t0")
+    __slots__ = ("timers", "key", "args", "t0", "parent", "traced")
 
     def __init__(self, timers: Any, key: str, args: Optional[Dict[str, Any]]) -> None:
         self.timers = timers
@@ -198,15 +257,24 @@ class _Stage:
         self.args = args
 
     def __enter__(self) -> "_Stage":
+        self.traced = _enabled
+        if self.traced:
+            st = _stack()
+            self.parent = st[-1] if st else None
+            st.append(self.key)
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         t1 = time.perf_counter_ns()
         self.timers[self.key] += (t1 - self.t0) * 1e-9
-        if _enabled:
-            _buffer().add(self.key, self.t0 // 1000, max((t1 - self.t0) // 1000, 0),
-                          None, self.args)
+        if self.traced:
+            _tls.stack.pop()
+            # rounded like now_us(), so a span nested in a stage ends
+            # inside it
+            t0_us = self.t0 // 1000
+            _buffer().add(self.key, t0_us, max(t1 // 1000 - t0_us, 0),
+                          None, _with_parent(self.args, self.parent))
         return None
 
     def set(self, **kw: Any) -> None:
@@ -225,9 +293,10 @@ class _AsyncHandle:
     __slots__ = ("kind", "lane", "args", "t0", "buf")
 
     def __init__(self, kind: str, lane: str, args: Optional[Dict[str, Any]]) -> None:
+        st = _stack()
         self.kind = kind
         self.lane = lane
-        self.args = args
+        self.args = _with_parent(args, st[-1] if st else None)
         self.t0 = now_us()
         self.buf = _buffer()
 

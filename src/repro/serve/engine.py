@@ -43,7 +43,7 @@ from repro.core.feature_cache import FeatureCache
 from repro.core.mfg import assemble
 from repro.core.sampling import sample_khop
 from repro.models import gnn as G
-from repro.obs import trace
+from repro.obs import trace, xfer
 from repro.obs.log import get_logger
 from repro.obs.metrics import MetricRegistry
 from repro.serve.admission import AdmissionQueue, Query, QueryFuture
@@ -128,7 +128,6 @@ class QueryEngine:
         self.saturate_depth = (int(saturate_depth) if saturate_depth
                                is not None else 4 * max_batch)
         self.metrics = metrics if metrics is not None else MetricRegistry()
-        self._h_latency = self.metrics.histogram("serve.latency_us")
         self._h_batch = self.metrics.histogram("serve.batch_queries")
         self._c_queries = self.metrics.counter("serve.queries")
         self._c_fallback = self.metrics.counter("serve.fallback")
@@ -258,7 +257,6 @@ class QueryEngine:
             scores = self.edgebank.predict(src, dst, ts)
         lat = time.perf_counter() - t0
         self._c_fallback.add()
-        self._h_latency.observe(lat * 1e6)
         return QueryResult(kind="link", tier="edgebank",
                            version=self.edgebank.version,
                            latency_s=lat, scores=scores)
@@ -299,6 +297,14 @@ class QueryEngine:
 
     def _process(self, batch: List[Query]) -> None:
         with trace.span("serve.batch", queries=len(batch)) as sp:
+            if trace.enabled():
+                # admission: the batch has just left the queue; ``depth``
+                # counts the queries still waiting behind it
+                now = time.perf_counter()
+                waits = [now - q.t_submit for q in batch]
+                sp.set(queue_wait_ms_sum=sum(waits) * 1e3,
+                       queue_wait_ms_max=max(waits) * 1e3,
+                       depth=self.queue.depth)
             self._drain_invalidations()
             handle = self.publisher.current()
             if handle is None:
@@ -333,7 +339,7 @@ class QueryEngine:
     def _fetch_memory(self):
         if not self.cfg.use_memory:
             return None
-        return lambda ids: self.state.get_memory(ids)[0]
+        return lambda ids: xfer.gather(self.state.get_memory, ids)[0]
 
     def _build_forwards(self) -> None:
         cfg = self.cfg
@@ -387,9 +393,9 @@ class QueryEngine:
         layers, hops = self._sample_assemble(handle, seeds, seed_ts)
         with trace.span("serve.forward", lanes=len(seeds)):
             if link:
-                out = np.asarray(self._link_fwd(handle.params, hops))
+                out = xfer.d2h(self._link_fwd(handle.params, hops))
             else:
-                out = np.asarray(self._embed_fwd(handle.params, hops))
+                out = xfer.d2h(self._embed_fwd(handle.params, hops))
         l0 = layers[0]
         nbr_ids = np.asarray(l0.nbr_ids)
         nbr_ts = np.asarray(l0.nbr_ts)
@@ -405,7 +411,6 @@ class QueryEngine:
                     nbrs["dst_ids"] = nbr_ids[m + off:m + off + k]
                     nbrs["dst_mask"] = nbr_mask[m + off:m + off + k]
             lat = time.perf_counter() - q.t_submit
-            self._h_latency.observe(lat * 1e6)
             res = QueryResult(
                 kind=q.kind, tier="gnn", version=handle.version,
                 latency_s=lat, nbrs=nbrs,

@@ -1,6 +1,7 @@
 """repro.obs: span tracer sharp edges, metric registry semantics,
 report round-trip, canonical transport-stats schema, logger routing,
 and trace-vs-metrics agreement on a real trainer."""
+import gc
 import json
 import threading
 
@@ -24,11 +25,15 @@ from repro.dist.transport import (STATS_KEYS, LocalTransport,
 
 @pytest.fixture(autouse=True)
 def _clean_trace():
+    # an automatic collection would add a ``gc`` span to the exact event
+    # counts below; the gc test collects by hand
+    gc.disable()
     trace.disable()
     trace.reset()
     yield
     trace.disable()
     trace.reset()
+    gc.enable()
 
 
 # ---------------------------------------------------------------------------
@@ -143,6 +148,87 @@ def test_concurrent_threads_do_not_corrupt(n_threads, per_thread):
             range(per_thread))
     trace.reset()
     trace.disable()
+
+
+def test_enable_installs_one_gc_callback_and_records_collections():
+    before = list(gc.callbacks)
+    trace.enable()
+    trace.enable()                       # twice: still one callback
+    assert len(gc.callbacks) == len(before) + 1
+    with trace.span("round"):
+        gc.collect()                     # one collection, generation 2
+    trace.disable()
+    assert gc.callbacks == before
+    gc.collect()                         # off: not recorded
+    evs = [e for e in trace.events() if e["kind"] == "gc"]
+    assert len(evs) == 1
+    (ev,) = evs
+    assert ev["args"]["generation"] == 2
+    assert ev["args"]["collected"] >= 0
+    assert ev["args"]["parent"] == "round"
+    assert ev["tid"] == threading.get_ident()
+
+
+def test_parent_is_innermost_open_span_per_thread():
+    trace.enable()
+    timers = MetricRegistry().timers("fetch")
+    seen = {}
+
+    def other_thread():
+        with trace.span("worker"):
+            pass
+        seen["done"] = True
+
+    with trace.span("round"):
+        with trace.stage(timers, "fetch", phase="assemble"):
+            with trace.span("xfer.h2d", bytes=4):
+                pass
+            # a thread started inside a span does not inherit it
+            t = threading.Thread(target=other_thread)
+            t.start()
+            t.join(timeout=30)
+            h = trace.begin_async("device.step", lane="device")
+        trace.end_async(h)
+        with trace.span("after"):
+            pass
+    with trace.span("top"):
+        pass
+    assert not t.is_alive() and seen["done"]
+    parent = {e["kind"]: e["args"].get("parent") for e in trace.events()}
+    assert parent == {"round": None, "fetch": "round", "xfer.h2d": "fetch",
+                      "worker": None, "device.step": "fetch",
+                      "after": "round", "top": None}
+
+
+def test_xfer_helpers_span_only_when_tracing():
+    import jax
+    import jax.numpy as jnp
+    from repro.obs import xfer
+
+    host = np.arange(12, dtype=np.float64).reshape(3, 4)
+    rows = lambda ids: host[ids]                       # noqa: E731
+    for on in (False, True):
+        trace.reset()
+        if on:
+            trace.enable()
+        dev = xfer.h2d(host, jnp.float32)
+        assert isinstance(dev, jax.Array) and dev.dtype == jnp.float32
+        xfer.h2d(dev)                                  # on the device
+        back = xfer.d2h(dev, np.float64)
+        np.testing.assert_array_equal(back, host)
+        assert xfer.d2h(host) is host                  # no copy, no span
+        np.testing.assert_array_equal(xfer.gather(rows, [2, 0]),
+                                      host[[2, 0]])
+        evs = trace.events()
+        if not on:
+            assert evs == []
+            continue
+        got = sorted((e["kind"], e["args"].get("bytes", e["args"].get(
+            "rows"))) for e in evs)
+        # the upload moves float32 (4 bytes a value); arrays already on
+        # the device, or already on the host, move nothing
+        assert got == [("state.gather", 2), ("xfer.d2h", 48),
+                       ("xfer.h2d", 48)]
 
 
 # ---------------------------------------------------------------------------

@@ -3,6 +3,8 @@ buffering, the prefetch/finalize split of FeatureAssembler (TGN memory
 blobs must observe the previous step's commit), ragged padding helpers,
 and the headline numerics guarantee: pipelined == serial execution,
 step for step."""
+import gc
+
 import numpy as np
 
 
@@ -12,6 +14,7 @@ from repro.core.pipeline import (FeatureAssembler, PipelineEngine,
                                  pad_tail, pow2_pad_len)
 from repro.core.sampling import SampledLayer
 from repro.data.events import synth_ctdg
+from repro.obs import trace
 
 STREAM = synth_ctdg(n_nodes=160, n_events=1200, t_span=15_000,
                     d_node=8, d_edge=8, seed=9)
@@ -287,3 +290,102 @@ def test_trainer_resumes_after_mid_round_failure(overlap):
     m = tr.train_round(STREAM.slice(WARM + ROUND, WARM + 2 * ROUND),
                        epochs=1)
     assert np.isfinite(m.loss) and 0.0 <= m.ap <= 1.0
+
+
+# ---------------------------------------------------------------------------
+# transfer, row-read and commit spans (repro.obs.xfer)
+# ---------------------------------------------------------------------------
+
+TGN_TINY = tgn(d_node=8, d_edge=8, d_time=8, d_hidden=16, d_memory=12,
+               fanouts=(4,), batch_size=64)
+
+
+def _tgn_trainer():
+    tr = ContinuousTrainer(TGN_TINY, STREAM, threshold=16, cache_ratio=0.2,
+                           lr=5e-4, seed=0)
+    tr.ingest(STREAM.slice(0, WARM))
+    return tr
+
+
+def _traced(fn):
+    trace.disable()
+    trace.reset()
+    trace.enable()
+    try:
+        fn()
+    finally:
+        trace.disable()
+    evs = trace.events()
+    trace.reset()
+    return evs
+
+
+def test_untraced_round_records_nothing_and_keeps_gc_callbacks():
+    trace.disable()
+    trace.reset()
+    callbacks = list(gc.callbacks)
+    tr = _tgn_trainer()
+    tr.train_round(STREAM.slice(WARM, WARM + ROUND), epochs=1)
+    gc.collect()
+    assert trace.events() == []
+    assert gc.callbacks == callbacks
+
+
+def test_fetch_h2d_bytes_equal_the_uploaded_arrays():
+    """The ``bytes`` of the ``xfer.h2d`` spans under ``fetch`` add up to
+    what one batch uploads: per cache fetch its padded ids and its miss
+    bucket (twice: the merged output and the cache update), then the
+    assembled hops and the TGN memory blobs."""
+    tr = _tgn_trainer()
+    tr.train_round(STREAM.slice(WARM, WARM + ROUND), epochs=1)  # pending
+    B = TGN_TINY.batch_size
+    lo = WARM + ROUND
+    out = {}
+
+    def one_batch():
+        staged = tr._stage_batch(STREAM.src[lo:lo + B],
+                                 STREAM.dst[lo:lo + B], STREAM.ts[lo:lo + B])
+        out["batch"] = tr.assembler.finalize(staged)
+
+    evs = _traced(one_batch)
+    batch = out["batch"]
+    h2d = [e for e in evs if e["kind"] == "xfer.h2d"]
+    assert {e["args"]["parent"] for e in h2d} == {"fetch"}
+
+    def bucket(n):
+        return max(8, 1 << int(np.ceil(np.log2(n))))
+    n_dst, k = 3 * B, TGN_TINY.fanouts[0]
+    cache = sum(bucket(n) * 4 + 2 * bucket(n) * d * 4
+                for n, d in ((n_dst, TGN_TINY.d_node),
+                             (n_dst * k, TGN_TINY.d_node),
+                             (n_dst * k, TGN_TINY.d_edge)))
+    hops = sum(v.nbytes for hop in batch["hops"] for v in hop.values())
+    blobs = sum(v.nbytes for pair in batch["mem_blobs"] for blob in pair
+                for v in blob.values())
+    assert sum(e["args"]["bytes"] for e in h2d) == cache + hops + blobs
+
+
+def test_transfer_spans_lie_inside_fetch_commit_or_ingest():
+    """On a traced round every ``xfer.*`` and ``state.gather`` span lies
+    inside a ``fetch`` or ``memory.commit`` span of its own thread, or in
+    ``ingest`` (the caches' invalidation), and names that span as its
+    parent."""
+    tr = _tgn_trainer()
+    evs = _traced(lambda: tr.train_round(
+        STREAM.slice(WARM, WARM + ROUND), epochs=1))
+    outer = [e for e in evs
+             if e["kind"] in ("fetch", "memory.commit", "ingest")]
+    inner = [e for e in evs
+             if e["kind"].startswith("xfer.") or e["kind"] == "state.gather"]
+    assert {e["kind"] for e in inner} == {"xfer.h2d", "xfer.d2h",
+                                          "state.gather"}
+    assert {e["kind"] for e in outer} == {"fetch", "memory.commit",
+                                          "ingest"}
+    for e in inner:
+        holders = [o for o in outer if o["tid"] == e["tid"]
+                   and o["ts_us"] <= e["ts_us"]
+                   and e["ts_us"] + e["dur_us"] <= o["ts_us"] + o["dur_us"]]
+        assert holders, e
+        assert e["args"]["parent"] in {o["kind"] for o in holders}, e
+    commits = [e for e in evs if e["kind"] == "memory.commit"]
+    assert sum(e["args"]["nodes"] for e in commits) > 0

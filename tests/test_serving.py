@@ -16,6 +16,7 @@ Pins the PR's contracts:
   saturation;
 * EdgeBank correctness against a brute-force recency table.
 """
+import gc
 import threading
 import time
 
@@ -29,6 +30,7 @@ from repro.core.dgraph import DynamicGraph
 from repro.core.sampling import oracle_sample
 from repro.core.snapshot import build_snapshot, refresh_snapshot
 from repro.data.events import synth_ctdg
+from repro.obs import trace
 from repro.serve import (AdmissionQueue, EdgeBank, HandlePublisher,
                          Query, QueryEngine, QueryFuture)
 
@@ -415,3 +417,72 @@ def test_admission_queue_backpressure_and_close():
     q.close()
     assert q.next_batch() is None               # drained + closed
     assert not q.submit(mk())                   # closed rejects
+
+
+# ---------------------------------------------------------------------------
+# tracing of the serving worker
+# ---------------------------------------------------------------------------
+
+
+def _served(n_queries, *, max_batch, pause_s=0.0):
+    """Queue ``n_queries`` link queries on an idle engine, wait
+    ``pause_s``, then start the worker and collect the answers."""
+    stream = synth_ctdg(n_nodes=50, n_events=200, d_node=D, d_edge=D,
+                        seed=17)
+    tr = _trainer(stream, tgn(d_node=D, d_edge=D, d_time=4, d_hidden=8,
+                              d_memory=6, fanouts=(4,), batch_size=32))
+    eng = QueryEngine.attach(tr, max_batch=max_batch, admit_timeout_s=0.01,
+                             start=False)
+    tr.ingest(stream.slice(0, 200))
+    t_q = np.full(1, float(stream.ts.max()) + 1, np.float32)
+    futs = [eng.submit_link([i], [i + 1], t_q) for i in range(n_queries)]
+    time.sleep(pause_s)
+    eng.start()
+    try:
+        return [f.result(60) for f in futs]
+    finally:
+        eng.stop()
+
+
+def test_untraced_served_batch_records_nothing():
+    trace.disable()
+    trace.reset()
+    callbacks = list(gc.callbacks)
+    results = _served(3, max_batch=8)
+    gc.collect()
+    assert len(results) == 3
+    assert trace.events() == []
+    assert gc.callbacks == callbacks
+
+
+def test_serve_batch_records_queue_wait_and_depth():
+    trace.disable()
+    trace.reset()
+    trace.enable()
+    try:
+        results = _served(6, max_batch=4, pause_s=0.05)
+    finally:
+        trace.disable()
+    evs = trace.events()
+    trace.reset()
+    assert len(results) == 6
+    batches = sorted((e for e in evs if e["kind"] == "serve.batch"),
+                     key=lambda e: e["ts_us"])
+    assert [b["args"]["queries"] for b in batches] == [4, 2]
+    # the first batch left two queries behind it; all waited the pause
+    assert [b["args"]["depth"] for b in batches] == [2, 0]
+    for b in batches:
+        a = b["args"]
+        assert a["queue_wait_ms_max"] >= 50.0
+        assert a["queries"] * 50.0 <= a["queue_wait_ms_sum"] \
+            <= a["queries"] * a["queue_wait_ms_max"] + 1e-9
+    # the engine's device reads sit under its batch spans
+    worker = {b["tid"] for b in batches}
+    d2h = [e for e in evs if e["kind"] == "xfer.d2h" and e["tid"] in worker]
+    assert d2h and {e["args"]["parent"] for e in d2h} <= {
+        "serve.batch", "serve.fetch", "serve.forward"}
+    assert "serve.forward" in {e["args"]["parent"] for e in d2h}
+    # the TGN memory rows are read from the host state inside the fetch
+    gathers = [e for e in evs if e["kind"] == "state.gather"
+               and e["tid"] in worker]
+    assert "serve.fetch" in {e["args"]["parent"] for e in gathers}
