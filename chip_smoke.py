@@ -239,14 +239,12 @@ def _timed(fn, *args, **kw):
 def phase_kernels(tr, layer, *, seed, expect_mosaic):
     import jax
     import jax.numpy as jnp
-    from repro.core.rand import gumbel_noise
     from repro.kernels.cache_gather.ops import cache_gather_pallas
     from repro.kernels.cache_gather.ref import cache_gather_ref
     from repro.kernels.temporal_attn.ops import temporal_attn_pallas
     from repro.kernels.temporal_attn.ref import temporal_attn_ref
     from repro.kernels.temporal_sample.ops import temporal_sample_pallas
-    from repro.kernels.temporal_sample.ref import (
-        temporal_sample_ref, temporal_sample_uniform_ref)
+    from repro.kernels.temporal_sample.ref import temporal_sample_ref
 
     mosaic = ("to a Mosaic kernel" if expect_mosaic
               else "to the interpreter")
@@ -259,25 +257,17 @@ def phase_kernels(tr, layer, *, seed, expect_mosaic):
             dev["pages_eid"], dev["pages_ts"], dev["pages_valid"],
             targets, jnp.asarray(layer.dst_times),
             jnp.full((n,), -jnp.inf, jnp.float32), jnp.ones((n,), bool))
-    key = jax.random.PRNGKey(seed)
     S, C = dev["page_table"].shape[1], dev["pages_ts"].shape[1]
-    for policy, kw, ref in (
-            ("recent", {}, lambda: temporal_sample_ref(*args, k=k)),
-            ("uniform", {"rng_key": key},
-             lambda: temporal_sample_uniform_ref(
-                 *args, gumbel_noise(key, (n, S, C)), k=k))):
-        check(_compiled_kernel(temporal_sample_pallas, *args, k=k,
-                               policy=policy, **kw) == expect_mosaic,
-              f"temporal_sample {policy} lowers {mosaic}")
-        got, wall = _timed(temporal_sample_pallas, *args, k=k,
-                           policy=policy, **kw)
-        want = ref()
-        fact("kernels", kernel=f"temporal_sample/{policy}", targets=n,
-             pages=S, lanes=C, first_call_wall_s=wall,
-             sampled=int(np.asarray(want[3]).sum()))
-        check(all(np.array_equal(np.asarray(a), np.asarray(b))
-                  for a, b in zip(got, want)),
-              f"temporal_sample {policy} == ref exactly")
+    check(_compiled_kernel(temporal_sample_pallas, *args, k=k)
+          == expect_mosaic, f"temporal_sample recent lowers {mosaic}")
+    got, wall = _timed(temporal_sample_pallas, *args, k=k)
+    want = temporal_sample_ref(*args, k=k)
+    fact("kernels", kernel="temporal_sample/recent", targets=n, pages=S,
+         lanes=C, first_call_wall_s=wall,
+         sampled=int(np.asarray(want[3]).sum()))
+    check(all(np.array_equal(np.asarray(a), np.asarray(b))
+              for a, b in zip(got, want)),
+          "temporal_sample recent == ref exactly")
 
     cache = tr.edge_cache.state
     ids = jnp.asarray(np.asarray(layer.nbr_eids).reshape(-1))

@@ -11,11 +11,26 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sampling import SampledLayer
-from repro.obs import xfer
+from repro.obs import trace, xfer
+
+
+def _record_hops(layers: List[SampledLayer]) -> None:
+    """With tracing on, a ``sampler.hops`` span whose args are the fused
+    dispatch's counts per hop: live ``targets``, their ``candidates``
+    summed, and the ``deep`` targets, whose candidates reach past their
+    ``scan_pages`` newest pages. Read after the layers' first read has
+    waited for the dispatch, so the copy waits for nothing."""
+    if not trace.enabled() or any(l.stats is None for l in layers):
+        return
+    with trace.span("sampler.hops") as sp:
+        s = np.asarray(jax.device_get([l.stats for l in layers]))
+        sp.set(targets=s[:, 0].tolist(), candidates=s[:, 1].tolist(),
+               deep=s[:, 2].tolist())
 
 
 def assemble(layers: List[SampledLayer],
@@ -25,8 +40,10 @@ def assemble(layers: List[SampledLayer],
              = None) -> List[Dict[str, jnp.ndarray]]:
     """Returns hops[l] dicts for repro.models.gnn.gnn_embed."""
     hops = []
-    for layer in layers:
+    for l, layer in enumerate(layers):
         dst_ids = xfer.d2h(layer.dst_nodes, np.int64)
+        if l == 0:
+            _record_hops(layers)
         nbr_ids = xfer.d2h(layer.nbr_ids, np.int64)
         eids = xfer.d2h(layer.nbr_eids, np.int64)
         N, K = nbr_ids.shape

@@ -4,18 +4,22 @@ Three interchangeable implementations (tests assert agreement):
 
   * ``oracle_sample``     — trusted numpy reference walking the dynamic
                             graph's block lists exactly as Algorithm 1.
-  * ``TemporalSampler``   — vectorized jnp path over the paged snapshot:
-                            one gather of the newest `scan_pages` pages per
-                            target, masked window intersection on the VPU,
-                            masked top-k selection (newest-K for recent,
-                            Gumbel-top-k for uniform — both O(W log k)).
-                            This is the TPU-native re-derivation of the
-                            paper's warp-per-target binary-search kernel:
-                            scalar binary search becomes a masked vector
-                            compare over 128-lane pages.
-  * Pallas kernel         — kernels/temporal_sample (recent + uniform
-                            policies), used via ``use_pallas=True`` and
-                            validated in interpret mode against both paths.
+  * ``TemporalSampler``   — vectorized jnp path over the paged snapshot,
+                            the time-indexed block search of Algorithm 1
+                            per target: a binary search of the node's
+                            newest-first chain by page ``t_min`` locates
+                            the page holding its newest edge before the
+                            query time; the pages' earlier-edge counts
+                            (``page_before``) plus one in-page compare
+                            count the candidates. ``recent`` takes the
+                            newest k from a ``scan_pages``-page window
+                            starting at the located page; ``uniform``
+                            draws k distinct ranks among all candidates
+                            and maps each to its (page, lane) by a second
+                            search over the counts — O(k log P).
+  * Pallas kernel         — kernels/temporal_sample, the ``recent``
+                            window (``use_pallas=True``), validated in
+                            interpret mode against both paths.
 
 Static shapes: every hop pads targets to a fixed budget and returns masked
 (N, K) neighbor tiles, so the whole GNN step jits once per shape. The
@@ -24,11 +28,13 @@ targets/times/masks never leave the device, and the paged snapshot itself
 is device-resident — ``refresh()`` applies SnapshotDeltas as in-place
 donated row updates rather than re-uploading (README "Sampling pipeline").
 
-Bounded work note: device paths scan the newest ``scan_pages`` pages per
-target (kernel-friendly bounded work, recency-biased truncation for very
-deep histories); the oracle scans everything. With the paper's adaptive
-block sizing a hub node's page holds ``tau`` edges, so 16 pages cover
-4k+ newest edges per node — far beyond the fanouts used by the models.
+Bounded work note: no path scans a node's whole history. The mirror
+holds every page of every chain (the page table at a power-of-two
+width), and each target costs two binary searches of its chain plus,
+for ``uniform``, one search per draw; ``recent`` gathers ``scan_pages``
+pages from the located one back, which hold its k newest candidates
+(a page holds at least ``min_block`` edges) unless deletions emptied
+them. ``uniform`` and ``window`` see every earlier edge, however deep.
 """
 from __future__ import annotations
 
@@ -43,7 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dgraph import DynamicGraph, NULL
-from repro.core.rand import gumbel_noise
 from repro.obs import trace
 from repro.core.snapshot import GraphSnapshot, build_snapshot
 
@@ -63,6 +68,10 @@ class SampledLayer:
     nbr_eids: np.ndarray | jnp.ndarray     # (N, K) int32
     nbr_ts: np.ndarray | jnp.ndarray       # (N, K) float32
     mask: np.ndarray | jnp.ndarray         # (N, K) bool
+    # (3,) int32 from the fused dispatch: live targets, their candidates
+    # summed, targets with candidates older than their scan_pages newest
+    # pages; None where the layer was not sampled on the device
+    stats: Optional[np.ndarray | jnp.ndarray] = None
 
     @property
     def fanout(self) -> int:
@@ -143,32 +152,120 @@ def _zero_key():
     return jax.random.PRNGKey(0)
 
 
-def _hop_jnp(dev, targets, t_end, t_start, tmask, rng_key, *,
-             k: int, policy: str, scan_pages: int):
-    """One hop for N targets over device-resident page arrays.
+def _first_true(pred, width: int, shape):
+    """Per row, the first column in ``[0, width)`` where the monotone
+    (False...False True...True) predicate ``pred(col)`` holds, or
+    ``width`` where none does: binary lifting, log2(width) + 1 probes.
+    ``width`` is a power of two."""
+    col = jnp.zeros(shape, jnp.int32)
+    step = width // 2
+    while step:
+        col = jnp.where(pred(col + step - 1), col, col + step)
+        step //= 2
+    return jnp.where(pred(col), col, width)
 
-    Returns (nbr (N,k), eid (N,k), ts (N,k), mask (N,k)). Traced inside
-    the fused dispatch — not jitted on its own.
-    """
-    page_table = dev["page_table"]
+
+def _page_at(dev, rows, col):
+    """Page id at (row, col) of the page table, NULL past its width."""
+    table = dev["page_table"]
+    width = table.shape[1]
+    pid = table[rows, jnp.clip(col, 0, width - 1)]
+    return jnp.where((col >= 0) & (col < width), pid, NULL)
+
+
+def _locate(dev, rows, t):
+    """Column of each target's newest page that starts before ``t``:
+    the page holding its newest edge earlier than ``t`` (chains are
+    newest-first and chronological, so ``t_min`` falls along a row).
+    Past the chain's end where no page does."""
+    tmin = dev["page_tmin"]
+
+    def starts_before(col):
+        p = _page_at(dev, rows, col)
+        return (p == NULL) | (tmin[jnp.maximum(p, 0)] < t)
+    return _first_true(starts_before, dev["page_table"].shape[1],
+                       rows.shape)
+
+
+def _rank_below(dev, rows, t, col):
+    """How many valid edges of each target are earlier than ``t``, given
+    the located column: the count of the page's older pages plus one
+    in-page comparison (lanes are chronological)."""
+    p = _page_at(dev, rows, col)
+    pc = jnp.maximum(p, 0)
+    in_page = jnp.sum(dev["pages_valid"][pc]
+                      & (dev["pages_ts"][pc] < t[:, None]), axis=-1)
+    return jnp.where(p != NULL, dev["page_before"][pc] + in_page, 0)
+
+
+def _targets(dev, targets, tmask):
+    rows_n = dev["page_table"].shape[0]
+    live = tmask & (targets >= 0) & (targets < rows_n)
+    return jnp.clip(targets, 0, rows_n - 1), live
+
+
+def _draw(dev, rows, lo, n, rng_key, k: int):
+    """``min(k, n)`` distinct ranks drawn uniformly from ``[lo, lo+n)``
+    per target (Floyd's algorithm, k static steps), each mapped to its
+    (page, lane) by a search over the chain's earlier-edge counts and
+    one in-page search over the valid lanes. O(k log P) per target."""
+    N = rows.shape[0]
+    m = jnp.minimum(n, k)
+    step = jnp.arange(k, dtype=jnp.int32)[None, :]
+    top = n[:, None] - m[:, None] + step          # inclusive bound, step i
+    u = jax.random.randint(rng_key, (N, k), 0, jnp.maximum(top + 1, 1))
+    picks = []
+    for i in range(k):
+        dup = jnp.zeros((N,), bool)
+        for q in picks:
+            dup = dup | (q == u[:, i])
+        picks.append(jnp.where(dup, top[:, i], u[:, i]))
+    on = step < m[:, None]                          # (N, k)
+    rank = jnp.where(on, lo[:, None] + jnp.stack(picks, axis=1), 0)
+
+    flat_rows = jnp.repeat(rows, k)
+    flat_rank = rank.reshape(-1)
+    before = dev["page_before"]
+
+    def holds_rank(col):        # the page's older pages end at or below r
+        p = _page_at(dev, flat_rows, col)
+        return (p == NULL) | (before[jnp.maximum(p, 0)] <= flat_rank)
+    col = _first_true(holds_rank, dev["page_table"].shape[1],
+                      flat_rows.shape)
+    page = jnp.maximum(_page_at(dev, flat_rows, col), 0)
+    q = flat_rank - before[page]
+    seen = jnp.cumsum(dev["pages_valid"][page].astype(jnp.int32), axis=-1)
+    lane = jnp.sum(seen <= q[:, None], axis=-1)
+    lane = jnp.minimum(lane, dev["pages_ts"].shape[1] - 1)
+    on_f = on.reshape(-1)
+
+    def pick(name, fill):
+        v = dev[name][page, lane]
+        return jnp.where(on_f, v, fill).reshape(N, k)
+    return (pick("pages_nbr", NULL), pick("pages_eid", NULL),
+            pick("pages_ts", 0.0), on)
+
+
+def _window_recent(dev, rows, live, t_end, t_start, first, *, k: int,
+                   scan_pages: int):
+    """The k newest valid edges in [t_start, t_end) among the
+    ``scan_pages`` pages from the located one back. Exact while those
+    pages hold k candidates, which they do unless deletions emptied
+    them: the pages older than the located one are full, of at least
+    ``min_block`` edges each."""
     pages_ts = dev["pages_ts"]
-    N = targets.shape[0]
+    N = rows.shape[0]
     page_cap = pages_ts.shape[1]
-    in_range = (targets >= 0) & (targets < page_table.shape[0])
-    safe_t = jnp.clip(targets, 0, page_table.shape[0] - 1)
-    pt = page_table[safe_t][:, :scan_pages]               # (N, S)
-    pvalid = (pt != NULL) & (tmask & in_range)[:, None]
-    ptc = jnp.clip(pt, 0, pages_ts.shape[0] - 1)
+    cols = first[:, None] + jnp.arange(scan_pages, dtype=jnp.int32)
+    pt = _page_at(dev, rows[:, None], cols)                # (N, S)
+    pvalid = (pt != NULL) & live[:, None]
+    ptc = jnp.maximum(pt, 0)
 
-    # gather page lanes, newest-first within page (pages are ascending
-    # ts). The paper's page-level t_min/t_max skip is subsumed by the
-    # per-lane window tests below — a dense vectorized gather computes
-    # every lane anyway, so the prefilter bought nothing.
+    # page lanes newest-first (lanes are chronological within a page)
     nbr = dev["pages_nbr"][ptc][:, :, ::-1]               # (N, S, C)
     eid = dev["pages_eid"][ptc][:, :, ::-1]
     ts = pages_ts[ptc][:, :, ::-1]
     val = dev["pages_valid"][ptc][:, :, ::-1]
-
     in_win = (val & pvalid[:, :, None]
               & (ts >= t_start[:, None, None])
               & (ts < t_end[:, None, None]))              # (N, S, C)
@@ -185,21 +282,12 @@ def _hop_jnp(dev, targets, t_end, t_start, tmask, rng_key, *,
         ts_f = jnp.pad(ts_f, pad, constant_values=0.0)
         m_f = jnp.pad(m_f, pad, constant_values=False)
         W = k
-
-    if policy == "recent":
-        # composite (validity, recency) score: valid lanes score by
-        # newest-first position, invalid strictly below all valid ones;
-        # masked top-k is O(W log k) vs the old argsort's O(W log W).
-        # float32 scores: XLA's CPU/TPU top-k fast path is float-only,
-        # and W < 2^24 keeps the positions exactly representable
-        idx = jnp.arange(W, dtype=jnp.float32)
-        score = jnp.where(m_f, -idx[None, :], -jnp.inf)
-        order = jax.lax.top_k(score, k)[1]
-    else:
-        # uniform among candidates: Gumbel top-k == sampling w/o replacement
-        score = jnp.where(m_f, gumbel_noise(rng_key, (N, W)), -jnp.inf)
-        order = jax.lax.top_k(score, k)[1]
-
+    # valid lanes score by newest-first position, invalid strictly
+    # below all valid ones; float32 scores (XLA's top-k fast path is
+    # float-only; W < 2^24 keeps positions exact)
+    idx = jnp.arange(W, dtype=jnp.float32)
+    score = jnp.where(m_f, -idx[None, :], -jnp.inf)
+    order = jax.lax.top_k(score, k)[1]
     take = jnp.take_along_axis
     out_m = take(m_f, order, axis=-1)
     out_nbr = jnp.where(out_m, take(nbr_f, order, axis=-1), NULL)
@@ -209,16 +297,42 @@ def _hop_jnp(dev, targets, t_end, t_start, tmask, rng_key, *,
 
 
 def _hop(dev, targets, t_end, t_start, tmask, rng_key, *, k: int,
-         policy: str, scan_pages: int, use_pallas: bool):
-    if use_pallas:
+         policy: str, bounded: bool, scan_pages: int, use_pallas: bool):
+    """One hop for N targets over the device-resident snapshot; traced
+    inside the fused dispatch. Returns (nbr, eid, ts, mask), each (N, k),
+    and the hop's (live targets, their candidates summed, targets with
+    candidates older than their ``scan_pages`` newest pages).
+
+    ``recent`` takes the k newest candidates from a ``scan_pages``-page
+    window that starts at the located page (the Pallas kernel when
+    ``use_pallas``); ``uniform`` draws among all candidates of the
+    whole history, bounded below by ``t_start`` when ``bounded``."""
+    rows, live = _targets(dev, targets, tmask)
+    first = _locate(dev, rows, t_end)
+    hi = _rank_below(dev, rows, t_end, first)
+    lo = (_rank_below(dev, rows, t_start, _locate(dev, rows, t_start))
+          if bounded else jnp.zeros_like(hi))
+    n = jnp.where(live, jnp.maximum(hi - lo, 0), 0)
+    S = min(scan_pages, dev["page_table"].shape[1])
+    # ranks below the count of the S-th newest page lie in older pages
+    edge = _page_at(dev, rows, jnp.full_like(rows, S - 1))
+    deep = (n > 0) & (lo < jnp.where(
+        edge != NULL, dev["page_before"][jnp.maximum(edge, 0)], 0))
+    stats = jnp.stack([jnp.sum(live), jnp.sum(n), jnp.sum(deep)]
+                      ).astype(jnp.int32)
+    if policy == "uniform":
+        out = _draw(dev, rows, lo, n, rng_key, k)
+    elif use_pallas:
         from repro.kernels.temporal_sample.ops import temporal_sample_pallas
-        return temporal_sample_pallas(
-            dev["page_table"][:, :scan_pages], dev["page_tmin"],
-            dev["page_tmax"], dev["pages_nbr"], dev["pages_eid"],
-            dev["pages_ts"], dev["pages_valid"], targets, t_end,
-            t_start, tmask, k=k, policy=policy, rng_key=rng_key)
-    return _hop_jnp(dev, targets, t_end, t_start, tmask, rng_key,
-                    k=k, policy=policy, scan_pages=scan_pages)
+        out = temporal_sample_pallas(
+            dev["page_table"], dev["page_tmin"], dev["page_tmax"],
+            dev["pages_nbr"], dev["pages_eid"], dev["pages_ts"],
+            dev["pages_valid"], targets, t_end, t_start, tmask, k=k,
+            start=first, scan_pages=S)
+    else:
+        out = _window_recent(dev, rows, live, t_end, t_start, first, k=k,
+                             scan_pages=S)
+    return out, stats
 
 
 def _khop_impl(dev, seeds, seed_ts, tmask0, rng_key, *,
@@ -227,23 +341,22 @@ def _khop_impl(dev, seeds, seed_ts, tmask0, rng_key, *,
     """The whole k-hop loop as ONE jitted dispatch: intermediate targets/
     times/masks stay on device; per-hop fanouts are static so each hop
     unrolls into the same trace. Returns a tuple of per-hop layer tuples
-    (dst_nodes, dst_times, dst_mask, nbr, eid, ts, mask)."""
+    (dst_nodes, dst_times, dst_mask, nbr, eid, ts, mask, stats)."""
     TRACE_COUNTS["khop"] += 1        # trace-time side effect (probe)
     targets, times, tmask = seeds, seed_ts, tmask0
     needs_rng = policy in ("uniform", "window")
+    bounded = policy == "window" and window > 0
     pol = "uniform" if policy == "window" else policy
     layers = []
     for h, k in enumerate(fanouts):
         sub = jax.random.fold_in(rng_key, h) if needs_rng else rng_key
         t_end = times
-        if policy == "window" and window > 0:
-            t_start = times - window
-        else:
-            t_start = jnp.full_like(times, -jnp.inf)
-        nbr, eid, ts, m = _hop(dev, targets, t_end, t_start, tmask, sub,
-                               k=k, policy=pol, scan_pages=scan_pages,
-                               use_pallas=use_pallas)
-        layers.append((targets, times, tmask, nbr, eid, ts, m))
+        t_start = (times - window if bounded
+                   else jnp.full_like(times, -jnp.inf))
+        (nbr, eid, ts, m), stats = _hop(
+            dev, targets, t_end, t_start, tmask, sub, k=k, policy=pol,
+            bounded=bounded, scan_pages=scan_pages, use_pallas=use_pallas)
+        layers.append((targets, times, tmask, nbr, eid, ts, m, stats))
         targets, times, tmask = (nbr.reshape(-1), ts.reshape(-1),
                                  m.reshape(-1))
     return tuple(layers)
@@ -266,19 +379,42 @@ def _scatter_rows(buf, rows, upd):
     return buf.at[rows].set(upd)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_cells(buf, rows, lanes, upd):
-    return buf.at[rows, lanes].set(upd)
-
-
 @jax.jit
 def _scatter_rows_cow(buf, rows, upd):
     return buf.at[rows].set(upd)
 
 
-@jax.jit
-def _scatter_cells_cow(buf, rows, lanes, upd):
-    return buf.at[rows, lanes].set(upd)
+@functools.partial(jax.jit, static_argnums=(1,))
+def _widen(table, width: int):
+    """The page table at a wider power-of-two width, new columns NULL."""
+    return jnp.pad(table, ((0, 0), (0, width - table.shape[1])),
+                   constant_values=NULL)
+
+
+def _group_set(bufs, idx, bits):
+    """Scatter one packed delta into several arrays at once: ``idx``
+    (n, d) holds each entry's row (and lane), ``bits`` (n, len(bufs))
+    its value per array as int32 bit patterns. One dispatch and two
+    uploads, whatever the number of arrays."""
+    where = tuple(idx[:, i] for i in range(idx.shape[1]))
+    out = []
+    for j, b in enumerate(bufs):
+        v = bits[:, j]
+        if b.dtype == jnp.bool_:
+            v = v != 0
+        elif b.dtype != v.dtype:
+            v = jax.lax.bitcast_convert_type(v, b.dtype)
+        out.append(b.at[where].set(v))
+    return tuple(out)
+
+
+_scatter_group = jax.jit(_group_set, donate_argnums=(0,))
+_scatter_group_cow = jax.jit(_group_set)
+
+
+def _bits(a: np.ndarray) -> np.ndarray:
+    """int32 bit patterns of a 4-byte or bool array (``_group_set``)."""
+    return a.view(np.int32) if a.dtype.itemsize == 4 else a.astype(np.int32)
 
 
 class DeviceMirror:
@@ -304,21 +440,23 @@ class DeviceMirror:
     #: timestamps / invalid lanes)
     _FILL = dict(page_table=NULL, pages_nbr=NULL, pages_eid=NULL,
                  pages_ts=np.inf, pages_valid=False,
-                 page_tmin=np.inf, page_tmax=-np.inf)
+                 page_tmin=np.inf, page_tmax=-np.inf, page_before=0)
+    #: device array -> snapshot attribute; the per-page arrays take the
+    #: changed (page, lane) cells or descriptor rows of a delta
+    _CELLS = dict(pages_nbr="nbr", pages_eid="eid", pages_ts="ts",
+                  pages_valid="valid")
+    _DESC = ("page_tmin", "page_tmax", "page_before")
 
-    def __init__(self, *, scan_pages: int, use_pallas: bool = False,
-                 donate: bool = True, quantize: bool = False):
-        self.scan_pages = int(scan_pages)
-        self.use_pallas = use_pallas
+    def __init__(self, *, donate: bool = True, quantize: bool = False):
         self.donate = donate
         # quantize=True rounds every device array's leading (row)
-        # dimension up to a power of two and pins the page-table width
-        # at scan_pages, so the mirrored shapes change O(log n) times as
-        # the graph grows instead of at every geometric reallocation.
-        # The jitted samplers retrace per distinct shape — for the
-        # serving wing (queries race ingest) an unquantized mirror would
-        # recompile sample_khop for every (growth step x batch bucket)
-        # pair, each a multi-hundred-ms stall on the query path.
+        # dimension up to a power of two, so the mirrored shapes change
+        # O(log n) times as the graph grows instead of at every
+        # geometric reallocation. The jitted samplers retrace per
+        # distinct shape — for the serving wing (queries race ingest) an
+        # unquantized mirror would recompile sample_khop for every
+        # (growth step x batch bucket) pair, each a multi-hundred-ms
+        # stall on the query path.
         self.quantize = quantize
         self.dev: Optional[dict] = None   # current device arrays
         self.version = -1                 # snapshot version mirrored
@@ -341,11 +479,11 @@ class DeviceMirror:
         return a if self.donate else np.array(a, copy=True)
 
     def _target_shape(self, name: str, host: np.ndarray) -> tuple:
-        if not self.quantize:
-            return host.shape
-        rows = 1 << max(3, int(host.shape[0] - 1).bit_length())
+        rows = host.shape[0]
+        if self.quantize:
+            rows = 1 << max(3, int(rows - 1).bit_length())
         if name == "page_table":
-            return (rows, self.scan_pages)
+            return (rows, 1 << max(0, int(host.shape[1] - 1).bit_length()))
         return (rows,) + host.shape[1:]
 
     def _quantized(self, name: str, host: np.ndarray) -> np.ndarray:
@@ -358,85 +496,105 @@ class DeviceMirror:
         out[tuple(slice(0, s) for s in host.shape)] = host
         return out
 
-    def _table_cols(self, snap: GraphSnapshot) -> int:
-        """The samplers never read past the scan_pages-newest pages, so
-        the mirror only holds that prefix of the page table — hub nodes
-        with thousand-page chains would otherwise blow the table up to
-        (N, max_pages)."""
-        return min(self.scan_pages, snap.page_table.shape[1])
+    @staticmethod
+    def _table_cols(snap: GraphSnapshot) -> int:
+        """Device width of the page table: the longest chain, rounded up
+        to a power of two. The mirror holds every page of every chain —
+        the samplers search a node's whole history by time — and the
+        rounding makes the table's shape, and the fused sampler's
+        compiles, change O(log P) times as the longest chain grows, and
+        gives the binary searches whole lifting steps. A dense (N, width)
+        table costs 4 bytes a cell: 5.6 MB for 10,984 nodes at 128
+        columns. It passes 1 GB near 2 million nodes at that width, or
+        once the longest chain passes 16,384 pages (a million edges of
+        one node at threshold 64: 1.4 GB over 10,984 nodes)."""
+        longest = int(snap.node_npages[:snap.n_live].max(initial=1))
+        return 1 << max(0, longest - 1).bit_length()
+
+    def _arrays(self, snap: GraphSnapshot) -> dict:
+        # the snapshot's own table keeps spare columns past the longest
+        # chain: they are NULL, and the device width covers the chains
+        out = {"page_table": snap.page_table[:, :self._table_cols(snap)]}
+        out.update({d: getattr(snap, a) for d, a in self._CELLS.items()})
+        out.update({d: getattr(snap, d) for d in self._DESC})
+        return out
 
     def _upload_full(self, snap: GraphSnapshot) -> None:
-        table = np.ascontiguousarray(
-            snap.page_table[:, :self._table_cols(snap)])
-        self.dev = dict(
-            page_table=jnp.asarray(self._quantized("page_table", table)),
-            pages_nbr=jnp.asarray(self._quantized("pages_nbr", snap.nbr)),
-            pages_eid=jnp.asarray(self._quantized("pages_eid", snap.eid)),
-            pages_ts=jnp.asarray(self._quantized("pages_ts", snap.ts)),
-            pages_valid=jnp.asarray(
-                self._quantized("pages_valid", snap.valid)),
-        )
-        self.last_refresh_bytes += (
-            table.nbytes + snap.nbr.nbytes + snap.eid.nbytes
-            + snap.ts.nbytes + snap.valid.nbytes)
-        if self.use_pallas:
-            # the Pallas kernel additionally consumes the t_min/t_max
-            # descriptors its page-skip logic reads
-            self.dev.update(
-                page_tmin=jnp.asarray(
-                    self._quantized("page_tmin", snap.page_tmin)),
-                page_tmax=jnp.asarray(
-                    self._quantized("page_tmax", snap.page_tmax)),
-            )
-            self.last_refresh_bytes += (snap.page_tmin.nbytes
-                                        + snap.page_tmax.nbytes)
+        host = self._arrays(snap)
+        self.dev = {k: jnp.asarray(self._quantized(k, a))
+                    for k, a in host.items()}
+        self.last_refresh_bytes += sum(a.nbytes for a in host.values())
 
-    def _scatter(self, name: str, host: np.ndarray, rows: np.ndarray,
-                 lanes: Optional[np.ndarray] = None) -> None:
-        """Mirror the changed entries of ``host`` into the device
-        buffer: whole rows, or (row, lane) cells when ``lanes`` is given
-        (the append-only page arrays — only the lanes filled since the
-        last refresh move over the wire). Reallocated host arrays
-        (geometric growth) and deltas covering most of the buffer fall
-        back to a full re-upload of that array. The index count is
-        padded to a power of two (repeating the first index, which is
-        idempotent) so the number of distinct traces stays O(log P)."""
+    def _full(self, name: str, host: np.ndarray, n: int,
+              of: int) -> bool:
+        """Re-upload ``host`` whole where the device buffer no longer has
+        its shape (geometric growth, a new table-width bucket) or the
+        ``n`` changed entries cover most of its ``of``; True where it
+        did, or where nothing changed."""
         dev = self.dev[name]
-        n = len(rows)
-        denom = host.shape[0] if lanes is None else host.size
         tgt = self._target_shape(name, host)
         if dev.shape == tgt and n == 0:
-            return
-        if dev.shape != tgt or n * 2 >= denom:
+            return True
+        if dev.shape != tgt or n * 2 >= of:
             self.dev[name] = jnp.asarray(self._quantized(name, host))
             self.last_refresh_bytes += host.nbytes
+            return True
+        return False
+
+    @staticmethod
+    def _pad_pow2(*idx: np.ndarray) -> list:
+        """Index arrays padded to a power-of-two length by repeating the
+        first entry (idempotent), so the scatters trace O(log P) times."""
+        n = len(idx[0])
+        pad = (1 << (n - 1).bit_length()) - n
+        return [np.concatenate([a, np.full(pad, a[0], a.dtype)])
+                for a in idx]
+
+    def _scatter_rows(self, name: str, host: np.ndarray,
+                      rows: np.ndarray) -> None:
+        """Mirror whole changed rows of a 2-D array (page-table rows,
+        validity rows flipped by deletions). A page table that needs a
+        wider bucket widens on the device: only chains that grew past
+        the old width hold entries there, and their rows are changed."""
+        dev = self.dev[name]
+        tgt = self._target_shape(name, host)
+        if dev.shape[0] == tgt[0] and dev.shape[1] < tgt[1]:
+            self.dev[name] = _widen(dev, tgt[1])
+        if self._full(name, host, len(rows), host.shape[0]):
             return
-        rows_f = _scatter_rows if self.donate else _scatter_rows_cow
-        cells_f = _scatter_cells if self.donate else _scatter_cells_cow
-        bucket = 1 << (n - 1).bit_length()
-        pad = bucket - n
-        rows_p = np.concatenate([rows, np.full(pad, rows[0], rows.dtype)])
-        if lanes is None:
-            upd = host[rows_p]
-            if upd.ndim == 2 and dev.shape[1] != upd.shape[1]:
-                # quantized page-table width: pad the gathered rows out
-                # to the device width (the graph hasn't grown chains
-                # that long yet)
-                wide = np.full((len(rows_p), dev.shape[1]),
-                               self._FILL[name], host.dtype)
-                wide[:, :upd.shape[1]] = upd
-                upd = wide
-            self.dev[name] = rows_f(
-                dev, jnp.asarray(rows_p, jnp.int32), jnp.asarray(upd))
-            self.last_refresh_bytes += upd.nbytes + rows_p.size * 4
-        else:
-            lanes_p = np.concatenate(
-                [lanes, np.full(pad, lanes[0], lanes.dtype)])
-            upd = host[rows_p, lanes_p]
-            self.dev[name] = cells_f(
-                dev, jnp.asarray(rows_p, jnp.int32),
-                jnp.asarray(lanes_p, jnp.int32), jnp.asarray(upd))
-            self.last_refresh_bytes += upd.nbytes + rows_p.size * 8
+        dev = self.dev[name]
+        [rows_p] = self._pad_pow2(rows)
+        upd = host[rows_p]
+        if dev.shape[1] != upd.shape[1]:
+            # the page table's device width is a power of two: pad the
+            # gathered rows out to it (chains that long do not exist yet)
+            wide = np.full((len(rows_p), dev.shape[1]), self._FILL[name],
+                           host.dtype)
+            wide[:, :upd.shape[1]] = upd
+            upd = wide
+        f = _scatter_rows if self.donate else _scatter_rows_cow
+        self.dev[name] = f(dev, jnp.asarray(rows_p, jnp.int32),
+                           jnp.asarray(upd))
+        self.last_refresh_bytes += upd.nbytes + rows_p.size * 4
+
+    def _scatter_entries(self, hosts: dict, *idx: np.ndarray) -> None:
+        """Mirror the same changed entries of several arrays — (page,
+        lane) cells of the append-only page arrays (only the lanes filled
+        since the last refresh move over the wire), or rows of the page
+        descriptors — packed into one upload and one dispatch."""
+        todo = [k for k, h in hosts.items()
+                if not self._full(k, h, len(idx[0]), h.size)]
+        if not todo:
+            return
+        idx_p = self._pad_pow2(*idx)
+        at = tuple(idx_p)
+        bits = np.stack([_bits(hosts[k][at]) for k in todo], axis=1)
+        where = np.stack(idx_p, axis=1).astype(np.int32)
+        f = _scatter_group if self.donate else _scatter_group_cow
+        out = f(tuple(self.dev[k] for k in todo), jnp.asarray(where),
+                jnp.asarray(bits))
+        self.dev.update(zip(todo, out))
+        self.last_refresh_bytes += bits.nbytes + where.nbytes
 
     def sync(self, snap: GraphSnapshot) -> dict:
         """Bring the mirror to ``snap``'s version; returns the device
@@ -456,22 +614,17 @@ class DeviceMirror:
                 # fresh dict per version: readers of the previous dict
                 # (pinned query handles) keep the old arrays
                 self.dev = dict(self.dev)
-            self._scatter("page_table",
-                          snap.page_table[:, :self._table_cols(snap)],
-                          d.table_rows)
-            self._scatter("pages_nbr", snap.nbr, d.cell_rows,
-                          d.cell_lanes)
-            self._scatter("pages_eid", snap.eid, d.cell_rows,
-                          d.cell_lanes)
-            self._scatter("pages_ts", snap.ts, d.cell_rows, d.cell_lanes)
-            self._scatter("pages_valid", snap.valid,
-                          d.cell_rows, d.cell_lanes)
+            host = self._arrays(snap)
+            self._scatter_rows("page_table", host["page_table"],
+                               d.table_rows)
+            self._scatter_entries({k: host[k] for k in self._CELLS},
+                                  d.cell_rows, d.cell_lanes)
             # deletions/offloads flip validity outside the appended
             # cells: those pages re-upload their (small) validity rows
-            self._scatter("pages_valid", snap.valid, d.valid_rows)
-            if self.use_pallas:
-                self._scatter("page_tmin", snap.page_tmin, d.page_rows)
-                self._scatter("page_tmax", snap.page_tmax, d.page_rows)
+            self._scatter_rows("pages_valid", host["pages_valid"],
+                               d.valid_rows)
+            self._scatter_entries({k: host[k] for k in self._DESC},
+                                  d.page_rows)
         self.version = snap.version
         self.snap_obj = snap
         self.total_refresh_bytes += self.last_refresh_bytes
@@ -493,11 +646,10 @@ def sample_khop(dev: dict, seeds, seed_ts, *, fanouts: Sequence[int],
     tmask = jnp.ones(targets.shape, bool)
     if key is None:
         key = _zero_key()
-    scan = min(int(scan_pages), dev["page_table"].shape[1])
     raw = _sample_khop(dev, targets, times, tmask, key,
                        fanouts=tuple(int(f) for f in fanouts),
                        policy=policy, window=float(window),
-                       scan_pages=scan, use_pallas=use_pallas)
+                       scan_pages=int(scan_pages), use_pallas=use_pallas)
     return [SampledLayer(*h) for h in raw]
 
 
@@ -541,8 +693,7 @@ class TemporalSampler:
         self.base_key = self._key
         # persistent device mirror of the snapshot (donated in-place
         # scatters: the trainer's sampler is the single consumer)
-        self._mirror = DeviceMirror(scan_pages=self.scan_pages,
-                                    use_pallas=use_pallas, donate=True)
+        self._mirror = DeviceMirror(donate=True)
 
     def _on_device(self):
         """Placement scope for mirror uploads + sampling dispatches."""
@@ -626,7 +777,6 @@ class TemporalSampler:
     def _dispatch(self, targets, times, tmask,
                   fanouts: Optional[Tuple[int, ...]] = None, key=None):
         dev = self._sync_device()
-        scan = min(self.scan_pages, self.snap.page_table.shape[1])
         if key is not None:
             sub = key
         elif self.policy in ("uniform", "window"):
@@ -638,7 +788,8 @@ class TemporalSampler:
         return _sample_khop(
             dev, targets, times, tmask, sub,
             fanouts=self.fanouts if fanouts is None else fanouts,
-            policy=self.policy, window=self.window, scan_pages=scan,
+            policy=self.policy, window=self.window,
+            scan_pages=self.scan_pages,
             use_pallas=self.use_pallas)
 
     def sample_hop(self, targets, times, tmask, k: int, key=None):
@@ -649,7 +800,7 @@ class TemporalSampler:
             targets = jnp.asarray(targets, jnp.int32)
             times = jnp.asarray(times, jnp.float32)
             tmask = jnp.asarray(tmask, bool)
-            [(_, _, _, nbr, eid, ts, m)] = self._dispatch(
+            [(_, _, _, nbr, eid, ts, m, _)] = self._dispatch(
                 targets, times, tmask, fanouts=(int(k),), key=key)
         return nbr, eid, ts, m
 

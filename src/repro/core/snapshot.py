@@ -12,6 +12,12 @@ refresh only appends/overwrites descriptor rows and the arena suffix that
 changed since the last refresh (mirroring the paper's "update without
 rebuild" property; see bench_graph_update.py).
 
+Besides t_min/t_max, each page carries ``page_before``: how many valid
+edges the older pages of its node's chain hold. With it a sampler finds
+how many of a node's edges precede a time, and which page holds the
+r-th of them, by binary searches over the chain (GNNFlow §4.2,
+Algorithm 1) instead of a scan of the whole history.
+
 Each refresh additionally records a ``SnapshotDelta`` — the exact set of
 page rows / page-table rows that changed plus a monotonically increasing
 version — so device-side consumers (``TemporalSampler``) can mirror the
@@ -44,7 +50,9 @@ class SnapshotDelta:
     version: int
     full: bool = False
     page_rows: np.ndarray = dataclasses.field(
-        default_factory=lambda: _EMPTY)   # pages whose fill/desc changed
+        default_factory=lambda: _EMPTY)   # pages whose fill or
+    #                                       descriptors (t_min, t_max,
+    #                                       page_before) changed
     table_rows: np.ndarray = dataclasses.field(
         default_factory=lambda: _EMPTY)   # nodes whose page chain changed
     valid_rows: np.ndarray = dataclasses.field(
@@ -69,6 +77,8 @@ class GraphSnapshot:
     page_tmin: np.ndarray         # (P,) float32
     page_tmax: np.ndarray         # (P,) float32
     page_start: np.ndarray        # (P,) int32  — arena offset
+    page_before: np.ndarray       # (P,) int32  — valid edges in the older
+    #                               pages of the page's chain
     page_cap: int                 # uniform padded page width for kernels
     # arena (padded per page to page_cap for the kernel path); arrays may
     # hold spare capacity rows beyond n_pages (never referenced by the
@@ -95,11 +105,28 @@ class GraphSnapshot:
         return (self.page_table.nbytes + self.node_npages.nbytes
                 + self.node_degree.nbytes + self.page_size.nbytes
                 + self.page_tmin.nbytes + self.page_tmax.nbytes
-                + self.page_start.nbytes)
+                + self.page_start.nbytes + self.page_before.nbytes)
 
     def edge_data_bytes(self) -> int:
         return (self.nbr.nbytes + self.eid.nbytes + self.ts.nbytes
                 + self.valid.nbytes)
+
+
+def _chain_before(counts: np.ndarray, nodes: np.ndarray) -> np.ndarray:
+    """For pages listed in block-id order: the sum of ``counts`` over the
+    earlier listed pages of the same node. A node's blocks are allocated
+    chronologically, so over a whole chain this is the count of edges in
+    the page's older pages."""
+    order = np.argsort(nodes, kind="stable")
+    c = counts[order].astype(np.int64)
+    excl = np.cumsum(c) - c
+    sn = nodes[order]
+    head = np.ones(len(sn), bool)
+    head[1:] = sn[1:] != sn[:-1]
+    first = np.maximum.accumulate(np.where(head, np.arange(len(sn)), 0))
+    out = np.empty(len(nodes), np.int32)
+    out[order] = excl - excl[first]
+    return out
 
 
 def build_snapshot(g: DynamicGraph, *, page_cap: Optional[int] = None
@@ -160,6 +187,8 @@ def build_snapshot(g: DynamicGraph, *, page_cap: Optional[int] = None
     tmax[:nb] = g.blk_tmax[:nb]
     degree = np.zeros(n, np.int32)
     degree[:g.n_nodes] = g.degree[:g.n_nodes]
+    before = np.zeros(nb_rows, np.int32)
+    before[:nb] = _chain_before(valid[:nb].sum(1), g.blk_node[:nb])
     return GraphSnapshot(
         page_table=page_table,
         node_npages=node_npages,
@@ -168,25 +197,31 @@ def build_snapshot(g: DynamicGraph, *, page_cap: Optional[int] = None
         page_tmin=tmin,
         page_tmax=tmax,
         page_start=starts.astype(np.int32),
+        page_before=before,
         page_cap=int(page_cap),
         nbr=nbr, eid=eid, ts=ts, valid=valid, n_pages=nb, n_live=n,
     )
 
 
-def _rebuild_page_table(g: DynamicGraph, n: int, nb: int):
-    max_pages = max(int(g.nblocks[:n].max()) if n else 1, 1)
-    page_table = np.full((n, max_pages), NULL, np.int32)
-    npages = g.nblocks[:n].astype(np.int32)
-    if nb:
-        bids = np.arange(nb, dtype=np.int64)
-        nodes = g.blk_node[:nb]
-        order = np.lexsort((bids, nodes))
-        sorted_nodes = nodes[order]
-        first_occ = np.searchsorted(sorted_nodes, np.arange(n))
-        pos_within = np.arange(nb) - first_occ[sorted_nodes]
-        col = npages[sorted_nodes] - 1 - pos_within
-        page_table[sorted_nodes, col] = order.astype(np.int32)
-    return page_table, npages
+def _before_of_new_pages(g: DynamicGraph, snap: GraphSnapshot,
+                         old_nb: int, nb: int) -> None:
+    """``page_before`` of the pages ``old_nb..nb-1`` appended by this
+    refresh. They are the newest pages of their chains: each chain's
+    first new page follows an old page (or none), whose own count and
+    earlier count are final, since a chain gains a page only once its
+    tail is full."""
+    new = np.arange(old_nb, nb)
+    nodes = g.blk_node[new]
+    within = _chain_before(snap.valid[new].sum(1), nodes)
+    prev = g.blk_prev[new]
+    head = prev < old_nb                    # an old page, or NULL
+    hp = np.maximum(prev[head], 0)
+    base = np.where(prev[head] >= 0,
+                    snap.page_before[hp] + snap.valid[hp].sum(1), 0)
+    hn = nodes[head]
+    order = np.argsort(hn)
+    grp = order[np.searchsorted(hn[order], nodes)]
+    snap.page_before[new] = within + base[grp]
 
 
 def refresh_snapshot(g: DynamicGraph, snap: GraphSnapshot
@@ -225,6 +260,7 @@ def refresh_snapshot(g: DynamicGraph, snap: GraphSnapshot
         snap.page_tmin = pad2(snap.page_tmin, np.inf)
         snap.page_tmax = pad2(snap.page_tmax, -np.inf)
         snap.page_start = pad2(snap.page_start, 0)
+        snap.page_before = pad2(snap.page_before, 0)
     page_rows = (np.concatenate([changed, np.arange(old_nb, nb)])
                  if nb > old_nb else changed)
     # pages are append-only: the minimal edge-data update is the lanes
@@ -251,6 +287,7 @@ def refresh_snapshot(g: DynamicGraph, snap: GraphSnapshot
         if nb > old_nb:
             new_ids = np.arange(old_nb, nb)
             snap.page_start[new_ids] = g.blk_start[new_ids]
+            _before_of_new_pages(g, snap, old_nb, nb)
     snap.n_pages = nb
     # node-level tables: delta update (only nodes whose chains changed)
     old_n = snap.n_live
@@ -311,6 +348,12 @@ def refresh_snapshot(g: DynamicGraph, snap: GraphSnapshot
             (new_valid != snap.valid[:nb]).any(axis=1))[0]
         snap.valid[:nb] = new_valid
         g._deleted_since_snapshot = False
+        # a flipped edge changes the count of every newer page of its
+        # chain
+        before = _chain_before(new_valid.sum(1), g.blk_node[:nb])
+        moved = np.nonzero(before != snap.page_before[:nb])[0]
+        snap.page_before[:nb] = before
+        page_rows = np.union1d(page_rows, moved)
     snap.version = base_version + 1
     snap.delta = SnapshotDelta(
         base_version, snap.version, full=False, page_rows=page_rows,

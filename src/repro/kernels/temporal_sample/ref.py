@@ -1,17 +1,12 @@
-"""Pure-jnp oracles for the temporal_sample kernel.
+"""Pure-jnp oracle for the temporal_sample kernel.
 
-Recent semantics: for each target i with window [t_start_i, t_end_i),
-walk its pages newest-first (pages are given newest-first; lanes within a
-page are oldest-first), collect valid in-window edges in newest-first
-order, return the first K.
-
-Uniform semantics: given the SAME (N, S, C) Gumbel noise the kernel
-consumes, a single global top-k over all in-window candidates — the
-kernel's page-by-page reservoir merge must agree exactly.
+For each target i with window [t_start_i, t_end_i), walk its pages
+newest-first (pages are given newest-first; lanes within a page are
+oldest-first), collect valid in-window edges in newest-first order,
+return the first K.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 NULL = -1
@@ -44,41 +39,6 @@ def temporal_sample_ref(page_table, page_tmin, page_tmax, pages_nbr,
     order = jnp.argsort(~in_win, axis=-1, stable=True)[:, :k]
     take = jnp.take_along_axis
     m = take(in_win, order, axis=-1)
-    return (jnp.where(m, take(nbr, order, axis=-1), NULL),
-            jnp.where(m, take(eid, order, axis=-1), NULL),
-            jnp.where(m, take(ts, order, axis=-1), 0.0),
-            m)
-
-
-def temporal_sample_uniform_ref(page_table, page_tmin, page_tmax,
-                                pages_nbr, pages_eid, pages_ts,
-                                pages_valid, targets, t_end, t_start,
-                                tmask, noise, *, k: int):
-    """Global Gumbel-top-k reference for the uniform kernel. ``noise``
-    must be the exact (N, S, C) array fed to the kernel (lanes NOT
-    flipped — the uniform path scores lanes in storage order)."""
-    N = targets.shape[0]
-    S = page_table.shape[1]
-    C = pages_ts.shape[1]
-    in_range = (targets >= 0) & (targets < page_table.shape[0])
-    safe_t = jnp.clip(targets, 0, page_table.shape[0] - 1)
-    pt = page_table[safe_t]                                # (N, S)
-    pvalid = (pt != NULL) & (tmask & in_range)[:, None]
-    ptc = jnp.clip(pt, 0, pages_ts.shape[0] - 1)
-    tmin, tmax = page_tmin[ptc], page_tmax[ptc]
-    p_hit = pvalid & (tmin < t_end[:, None]) & (tmax >= t_start[:, None])
-
-    nbr = pages_nbr[ptc].reshape(N, S * C)
-    eid = pages_eid[ptc].reshape(N, S * C)
-    ts = pages_ts[ptc].reshape(N, S * C)
-    val = pages_valid[ptc].reshape(N, S * C)
-    in_win = (val & jnp.repeat(p_hit, C, axis=1)
-              & (ts >= t_start[:, None]) & (ts < t_end[:, None]))
-
-    score = jnp.where(in_win, noise.reshape(N, S * C), -jnp.inf)
-    top_s, order = jax.lax.top_k(score, k)
-    take = jnp.take_along_axis
-    m = top_s > -jnp.inf
     return (jnp.where(m, take(nbr, order, axis=-1), NULL),
             jnp.where(m, take(eid, order, axis=-1), NULL),
             jnp.where(m, take(ts, order, axis=-1), 0.0),

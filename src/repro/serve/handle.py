@@ -58,9 +58,7 @@ class HandlePublisher:
         # donate=False: copy-on-write arrays so pinned handles stay
         # valid; quantize=True: pow2-bucketed device shapes so the
         # query-path samplers retrace O(log n) times under graph growth
-        self._mirror = DeviceMirror(scan_pages=scan_pages,
-                                    use_pallas=use_pallas, donate=False,
-                                    quantize=True)
+        self._mirror = DeviceMirror(donate=False, quantize=True)
         self.scan_pages = int(scan_pages)
         self.use_pallas = use_pallas
         self._current: Optional[SnapshotHandle] = None

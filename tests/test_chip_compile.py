@@ -14,7 +14,7 @@ Shapes: TGN (fanout (10,), ``recent``, batch 4000 -> 12,000 seeds) and
 TGAT (fanouts (10, 10), ``uniform``, batch 600 -> 1,800 seeds, 18,000
 second-hop targets) at the published widths of configs/tgn_gdelt.py,
 over a JODIE-Wikipedia-sized graph: 9,228 node rows, 65,536 pages of
-128 lanes.
+128 lanes, chains up to 64 pages (the mirror holds every page).
 """
 import jax
 import jax.numpy as jnp
@@ -31,7 +31,7 @@ from repro.kernels.temporal_attn.ops import temporal_attn_pallas
 from repro.kernels.temporal_sample.ops import temporal_sample_pallas
 
 HBM_BYTES = 16e9            # one v5e chip
-NODES, PAGES, LANES, SCAN = 9_228, 65_536, 128, 16
+NODES, PAGES, LANES, SCAN, CHAIN = 9_228, 65_536, 128, 16, 64
 
 
 @pytest.fixture(scope="module")
@@ -63,11 +63,13 @@ def _fits(compiled):
 
 
 def _mirror(sh):
-    return dict(page_table=_sds(sh, (NODES, SCAN), jnp.int32),
+    return dict(page_table=_sds(sh, (NODES, CHAIN), jnp.int32),
                 pages_nbr=_sds(sh, (PAGES, LANES), jnp.int32),
                 pages_eid=_sds(sh, (PAGES, LANES), jnp.int32),
                 pages_ts=_sds(sh, (PAGES, LANES)),
-                pages_valid=_sds(sh, (PAGES, LANES), jnp.bool_))
+                pages_valid=_sds(sh, (PAGES, LANES), jnp.bool_),
+                page_tmin=_sds(sh, (PAGES,)), page_tmax=_sds(sh, (PAGES,)),
+                page_before=_sds(sh, (PAGES,), jnp.int32))
 
 
 def _is_kernel(compiled):
@@ -111,18 +113,17 @@ def test_tgn_train_step_compiles(one_chip):
                                jax.tree.map(big, batch)).compile())
 
 
-@pytest.mark.parametrize("policy,n", [("recent", 12_000),
-                                      ("uniform", 18_000)])
+@pytest.mark.parametrize("policy,n", [("recent", 12_000)])
 def test_temporal_sample_kernel_compiles(one_chip, policy, n):
-    kw = {"rng_key": _sds(one_chip, (2,), jnp.uint32)} \
-        if policy == "uniform" else {}
+    """``recent`` as the sampler runs it, over the SCAN pages from each
+    target's located page."""
     m = _mirror(one_chip)
     c = temporal_sample_pallas.lower(
-        m["page_table"], _sds(one_chip, (PAGES,)), _sds(one_chip, (PAGES,)),
+        m["page_table"], m["page_tmin"], m["page_tmax"],
         m["pages_nbr"], m["pages_eid"], m["pages_ts"], m["pages_valid"],
         _sds(one_chip, (n,), jnp.int32), _sds(one_chip, (n,)),
         _sds(one_chip, (n,)), _sds(one_chip, (n,), jnp.bool_), k=10,
-        policy=policy, **kw).compile()
+        start=_sds(one_chip, (n,), jnp.int32), scan_pages=SCAN).compile()
     _is_kernel(_fits(c))
 
 
