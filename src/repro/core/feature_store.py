@@ -88,9 +88,20 @@ class _Dense:
         self.data[ids] = vals
 
     def get(self, ids: np.ndarray) -> np.ndarray:
-        out = np.zeros((len(ids), self.dim), np.float32)
-        ok = (ids >= 0) & (ids < self.size)
-        out[ok] = self.data[ids[ok]]
+        """Rows of ``ids`` as a fresh (N, dim) float32 array the caller
+        owns; negative ids and ids at or past ``size`` read as zeros.
+
+        Lock-free against one writer: ``data`` is read once and the
+        bound is clipped to that array, so a writer that grows the table
+        mid-read (``_ensure`` swaps in a grown copy, then raises
+        ``size``) can neither push the take past it nor hide a row
+        written before the read began."""
+        d = self.data
+        n = min(self.size, len(d))
+        ok = (ids >= 0) & (ids < n)
+        out = d.take(np.where(ok, ids, 0), axis=0)
+        if not ok.all():
+            out[~ok] = 0.0
         return out
 
 
@@ -256,6 +267,10 @@ class ReplicatedStateService(StateService):
 
     def _fetch(self, table: str, ids: np.ndarray, dim: int) -> np.ndarray:
         ids = np.asarray(ids, np.int64)
+        if self.n_parts == 1:
+            # partition 0, local_rank's own, owns every id >= 0: no
+            # owner lookup, nothing remote to account
+            return getattr(self.parts[0], table).get(ids)
         out = np.zeros((len(ids), dim), np.float32)
         if table == "edge":
             own = self._edge_owner.get(ids)[:, 0].astype(np.int64)

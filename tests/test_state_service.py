@@ -17,10 +17,14 @@ transports), client-side dedup, async prefetch serving without extra
 round trips, the bounded-staleness memory contract, and in-process
 trainer parity with ``state="sharded"``.
 """
+import sys
+import threading
+
 import numpy as np
 import pytest
 
-from repro.core.feature_store import ReplicatedStateService
+from repro.core import feature_store
+from repro.core.feature_store import ReplicatedStateService, _Dense
 from repro.dist.state import (ShardedStateService, pack_state_batch,
                               unpack_state_batch)
 from repro.dist.transport import OPS, LocalTransport, RpcTransport
@@ -123,6 +127,247 @@ def test_sharded_resident_bytes_are_one_over_p():
     m_r, t_r = rep.get_memory(own)
     np.testing.assert_array_equal(m_s, m_r)
     np.testing.assert_array_equal(t_s, t_r)
+
+
+# ---------------------------------------------------------------------------
+# row reads: one bounded take from a single snapshot of each table
+# ---------------------------------------------------------------------------
+
+
+def _masked_get(t, ids):
+    """The masked read ``_Dense.get`` made before the direct take: the
+    oracle the take must match bit for bit."""
+    out = np.zeros((len(ids), t.dim), np.float32)
+    ok = (ids >= 0) & (ids < t.size)
+    out[ok] = t.data[ids[ok]]
+    return out
+
+
+def _masked_fetch(svc, table, ids):
+    """The per-partition read ``ReplicatedStateService`` made before the
+    direct take, for any ``n_parts``."""
+    dim = getattr(svc.parts[0], table).dim
+    out = np.zeros((len(ids), dim), np.float32)
+    if table == "edge":
+        own = _masked_get(svc._edge_owner, ids)[:, 0].astype(np.int64)
+    else:
+        own = np.maximum(ids, 0) % svc.n_parts
+    for p in range(svc.n_parts):
+        sel = (own == p) & (ids >= 0)
+        if sel.any():
+            out[sel] = _masked_get(getattr(svc.parts[p], table), ids[sel])
+    return out
+
+
+def _assert_owned_copy(out, tables):
+    assert out.dtype == np.float32
+    assert out.flags.c_contiguous and out.flags.writeable
+    for t in tables:
+        assert not np.shares_memory(out, t.data)
+
+
+@pytest.mark.parametrize("table", ["node", "edge", "memory"])
+@pytest.mark.parametrize("n_parts", [1, 2, 4])
+def test_row_reads_match_masked_read(n_parts, table):
+    """Zero rows for negative ids, ids at and past ``size`` and ids in
+    the over-allocated tail (poisoned here, so a read that bounds by the
+    array's length shows); unregistered edges and unwritten rows as the
+    masked read gives them; an empty read; every result a fresh
+    C-contiguous float32 array that shares no memory with a table."""
+    rng = np.random.default_rng(n_parts)
+    svc = ReplicatedStateService(n_parts, d_node=6, d_edge=4, d_memory=5)
+    ids = np.unique(rng.integers(0, 300, 150))
+    svc.put_node_feats(ids, rng.normal(size=(len(ids), 6)).astype(
+        np.float32))
+    eids = np.arange(200)
+    svc.register_edges(eids[:150], rng.integers(0, 300, 150))
+    svc.put_edge_feats(eids[::2], rng.normal(size=(100, 4)).astype(
+        np.float32))
+    svc.put_memory(ids, rng.normal(size=(len(ids), 5)).astype(np.float32),
+                   rng.uniform(0, 100, len(ids)))
+    names = ["memory", "mem_ts"] if table == "memory" else [table]
+    tables = [getattr(part, n) for part in svc.parts for n in names]
+    for t in tables:
+        assert t.size < len(t.data)
+        t.data[t.size:] = 7.0
+    top = max(t.size for t in tables)
+    probes = [
+        np.zeros(0, np.int64),
+        np.array([-1, -5, -(2 ** 40)]),
+        np.array([top, top + 1, len(tables[0].data) - 1, 10 ** 9]),
+        np.arange(top),
+        np.arange(-3, len(tables[0].data) + 3),
+        rng.integers(-10, top + 50, 500),
+    ]
+    for probe in probes:
+        if table == "memory":
+            mem, ts = svc.get_memory(probe)
+            want = (_masked_fetch(svc, "memory", probe),
+                    _masked_fetch(svc, "mem_ts", probe)[:, 0])
+            got = (mem, ts)
+            _assert_owned_copy(mem, tables)
+        else:
+            got = (getattr(svc, f"get_{table}_feats")(probe),)
+            want = (_masked_fetch(svc, table, probe),)
+            _assert_owned_copy(got[0], tables)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape
+            np.testing.assert_array_equal(g.view(np.uint32),
+                                          w.view(np.uint32))
+        out = got[0]
+        assert not out[probe < 0].any()
+        assert not out[probe >= top].any()
+        # the caller owns the result: writing it leaves the table as is
+        before = [t.data.copy() for t in tables]
+        out[...] = -1.0
+        for t, b in zip(tables, before):
+            np.testing.assert_array_equal(t.data, b)
+
+
+@pytest.mark.parametrize("kind", ["dense", "service"])
+def test_row_reads_under_a_growing_writer(kind):
+    """A writer thread writes rows 0..n_rows-1 (row ``i`` holds ``i``) in
+    increasing chunks, growing the table at least five times, while a
+    reader thread reads random ids. No read raises, gives a row another
+    id's value, or misses a row written before the read began."""
+    dim, n_rows, chunk = 8, 12_000, 64
+    if kind == "dense":
+        table = _Dense(dim, initial=16)
+        write, read = table.set, table.get
+    else:
+        svc = ReplicatedStateService(1, d_node=dim, d_edge=4)
+        table = svc.parts[0].node
+        write, read = svc.put_node_feats, svc.get_node_feats
+    done = [0]                  # rows written before the current read
+    stop = threading.Event()
+    errors, reads, growths = [], [0], [0]
+
+    def writer():
+        try:
+            for lo in range(0, n_rows, chunk):
+                ids = np.arange(lo, min(lo + chunk, n_rows))
+                cap = len(table.data)
+                write(ids, np.repeat(ids[:, None], dim, 1).astype(
+                    np.float32))
+                growths[0] += len(table.data) != cap
+                done[0] = ids[-1] + 1
+        except Exception as e:  # noqa: BLE001 — asserted below
+            errors.append(e)
+        finally:
+            stop.set()
+
+    def reader():
+        rng = np.random.default_rng(0)
+        try:
+            while not stop.is_set() or reads[0] == 0:
+                w = done[0]
+                ids = rng.integers(-4, n_rows + 64, 512)
+                out = read(ids)
+                want = np.where((ids >= 0) & (ids < n_rows), ids, 0)
+                # rows written while the read ran may be seen in part
+                bad = (out != 0) & (out != want[:, None])
+                assert not bad.any(), ids[bad.any(1)]
+                early = (ids >= 0) & (ids < w)
+                np.testing.assert_array_equal(
+                    out[early], np.repeat(ids[early, None], dim, 1))
+                reads[0] += 1
+        except Exception as e:  # noqa: BLE001 — asserted below
+            errors.append(e)
+            stop.set()
+
+    threads = [threading.Thread(target=f, daemon=True)
+               for f in (reader, writer)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)       # interleave the threads finely
+    try:
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads), "read or write hung"
+    assert not errors, errors
+    assert growths[0] >= 5 and reads[0] > 0
+    np.testing.assert_array_equal(
+        read(np.arange(n_rows))[:, 0], np.arange(n_rows, dtype=np.float32))
+
+
+def test_row_read_bound_holds_when_the_writer_grows_mid_read():
+    """The writer grows the table at the worst moment: whenever the read
+    looks at ``size``. The bound must still fit the array the read
+    indexes, so the read neither raises nor reads past its snapshot."""
+
+    class GrowsOnSizeRead(_Dense):
+        armed = False
+
+        @property
+        def size(self):
+            if self.armed:
+                self.armed = False
+                self.set(np.arange(self._size, 3 * len(self.data)),
+                         np.ones((3 * len(self.data) - self._size, 2),
+                                 np.float32))
+            return self._size
+
+        @size.setter
+        def size(self, v):
+            self._size = v
+
+    t = GrowsOnSizeRead(2, initial=8)
+    t.set(np.arange(8), np.arange(16, dtype=np.float32).reshape(8, 2))
+    t.armed = True
+    out = t.get(np.array([7, 8, 23, -1]))
+    assert not t.armed and t.size == 24
+    assert out[0].tolist() == [14, 15]
+    assert not out[3].any()
+    assert ((out[1:3] == 0) | (out[1:3] == 1)).all()
+    np.testing.assert_array_equal(t.get(np.array([8, 23])), np.ones((2, 2)))
+
+
+def test_row_reads_take_no_lock():
+    """A read completes while the writer is stopped inside ``set``,
+    after the table grew and before the new rows landed; no object the
+    read path reaches holds a lock."""
+    t = _Dense(3, initial=4)
+    t.set(np.arange(4), np.arange(12, dtype=np.float32).reshape(4, 3))
+    entered, release = threading.Event(), threading.Event()
+
+    class Paused:
+        """Row values whose conversion waits until released: the write
+        stops where ``set`` copies the values in."""
+
+        def __array__(self, dtype=None, copy=None):
+            entered.set()
+            release.wait(10)
+            return np.full((2, 3), 9.0, dtype or np.float32)
+
+    w = threading.Thread(target=t.set, args=(np.array([20, 21]), Paused()),
+                         daemon=True)
+    w.start()
+    try:
+        assert entered.wait(10)
+        assert len(t.data) > 4              # grown, rows not yet written
+        out = t.get(np.array([3, 0, 20, 21, 30]))
+        np.testing.assert_array_equal(
+            out, [[9, 10, 11], [0, 1, 2], [0] * 3, [0] * 3, [0] * 3])
+    finally:
+        release.set()
+        w.join(10)
+    assert not w.is_alive()
+    np.testing.assert_array_equal(t.get(np.array([20, 21])),
+                                  np.full((2, 3), 9.0))
+    lock_types = (type(threading.Lock()), type(threading.RLock()))
+    svc = ReplicatedStateService(1, d_node=3, d_edge=3, d_memory=3)
+    reached = [vars(t), vars(svc), vars(feature_store)]
+    reached += [vars(part) for part in svc.parts]
+    reached += [vars(x) for part in svc.parts for x in vars(part).values()
+                if isinstance(x, _Dense)]
+    for ns in reached:
+        for name, v in ns.items():
+            assert not isinstance(v, lock_types + (threading.Condition,
+                                                   threading.Semaphore)), \
+                name
 
 
 # ---------------------------------------------------------------------------
