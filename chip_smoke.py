@@ -143,7 +143,7 @@ def phase_tgn(cfg, stream, *, warm, round_events, platform):
     check(len(losses) >= 8, f"tgn took {len(losses)} >= 8 steps")
     check(np.isfinite(losses).all(), "tgn losses finite")
     check(platforms(tr.params) == {platform}, f"tgn params on {platform}")
-    check(platforms(tr.sampler._mirror.dev) == {platform},
+    check(platforms(tr.sampler.mirror.dev) == {platform},
           f"tgn sampler mirror on {platform}")
 
     # one batch of the NEXT events, sampled against the trained graph
@@ -248,7 +248,7 @@ def phase_kernels(tr, layer, *, seed, expect_mosaic):
 
     mosaic = ("to a Mosaic kernel" if expect_mosaic
               else "to the interpreter")
-    dev, snap = tr.sampler._mirror.dev, tr._snap
+    dev, snap = tr.sampler.mirror.dev, tr._snap
     targets = jnp.asarray(layer.dst_nodes)
     n = targets.shape[0]
     k = layer.nbr_ids.shape[1]
@@ -350,7 +350,7 @@ def run_four_chips(cfg, stream, *, warm, round_events, rounds):
     for m, ranks in multi.samplers.samplers.items():
         for r, s in enumerate(ranks):
             fact("dist", sampler_mirror=f"machine{m}/rank{r}",
-                 device_ids=sorted({d.id for a in s._mirror.dev.values()
+                 device_ids=sorted({d.id for a in s.mirror.dev.values()
                                     for d in a.devices()}))
     check(platforms(multi.params) == {jax.devices()[0].platform},
           "dist params on the accelerator")

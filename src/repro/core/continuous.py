@@ -428,7 +428,7 @@ class ContinuousTrainer:
             self._snap = refresh_snapshot(self.graph, self._snap)
         # delta-upload: only the changed snapshot rows go to the device
         self.sampler.refresh(self._snap)
-        self._refresh_bytes += self.sampler.last_refresh_bytes
+        self._refresh_bytes += self.sampler.mirror.last_refresh_bytes
         # serving listeners see the new version only now — after the
         # snapshot refresh AND the feature/memory writes above, so a
         # query pinning the published handle finds every row it needs

@@ -25,8 +25,9 @@ Static shapes: every hop pads targets to a fixed budget and returns masked
 (N, K) neighbor tiles, so the whole GNN step jits once per shape. The
 entire k-hop loop is ONE jitted dispatch (``_sample_khop``): intermediate
 targets/times/masks never leave the device, and the paged snapshot itself
-is device-resident — ``refresh()`` applies SnapshotDeltas as in-place
-donated row updates rather than re-uploading (README "Sampling pipeline").
+is device-resident — ``refresh()`` applies SnapshotDeltas as
+copy-on-write row/cell scatters rather than re-uploading (README
+"Sampling pipeline").
 
 Bounded work note: no path scans a node's whole history. The mirror
 holds every page of every chain (the page table at a power-of-two
@@ -368,19 +369,13 @@ _sample_khop = jax.jit(
                      "use_pallas"))
 
 
-# device-mirror scatters.  The donated variants reuse the old buffer in
-# place (single-consumer trainer mirror: a steady-state refresh
-# transfers only the updated rows/cells).  The copy-on-write variants
-# allocate a fresh output buffer so PREVIOUS readers stay valid — the
-# serving wing's versioned read handles pin old buffers while ingest
-# publishes new ones (repro.serve.handle).
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_rows(buf, rows, upd):
-    return buf.at[rows].set(upd)
-
-
+# device-mirror scatters: a steady-state refresh transfers only the
+# updated rows/cells, and each scatter writes a fresh output buffer so
+# PREVIOUS readers stay valid — the serving wing's versioned read
+# handles pin old buffers while ingest publishes new ones
+# (repro.serve.handle).
 @jax.jit
-def _scatter_rows_cow(buf, rows, upd):
+def _scatter_rows(buf, rows, upd):
     return buf.at[rows].set(upd)
 
 
@@ -408,8 +403,7 @@ def _group_set(bufs, idx, bits):
     return tuple(out)
 
 
-_scatter_group = jax.jit(_group_set, donate_argnums=(0,))
-_scatter_group_cow = jax.jit(_group_set)
+_scatter_group = jax.jit(_group_set)
 
 
 def _bits(a: np.ndarray) -> np.ndarray:
@@ -418,26 +412,27 @@ def _bits(a: np.ndarray) -> np.ndarray:
 
 
 class DeviceMirror:
-    """Device-resident mirror of a :class:`GraphSnapshot`.
+    """Device-resident mirror of a :class:`GraphSnapshot` — the graph's
+    one device copy, owned by the trainer's ``TemporalSampler`` and
+    published as-is to the serving wing (``repro.serve.handle``).
 
-    Factored out of ``TemporalSampler`` so the trainer's sampler and the
-    online-serving read path share ONE mirror-maintenance implementation
-    (delta scatter when the snapshot's delta chains from the mirrored
-    version, full upload otherwise):
+    ``sync`` scatters the snapshot's delta when it chains from the
+    mirrored version and uploads in full otherwise. Every ``sync`` that
+    changes anything returns a FRESH dict whose updated arrays are new
+    buffers (copy-on-write at array granularity): a reader holding a
+    previously returned dict keeps a complete, immutable view of that
+    version, which is exactly what a versioned query handle pins.
 
-    * ``donate=True`` (the trainer mirror): scatters donate the old
-      buffer, so updates are in place and only one consumer may hold
-      the returned dict at a time;
-    * ``donate=False`` (the serving wing): every ``sync`` that changes
-      anything returns a FRESH dict whose updated arrays are new
-      buffers (copy-on-write at array granularity) — a reader holding a
-      previously returned dict keeps a complete, immutable view of that
-      version, which is exactly what a versioned query handle pins.
+    Every array's leading (row) dimension is rounded up to a power of
+    two, so the mirrored shapes change O(log n) times as the graph grows
+    instead of at every geometric reallocation: the jitted sampler and
+    scatters retrace per distinct shape, and on the query path each
+    retrace is a multi-hundred-ms stall.
     """
 
-    #: pad fill per device array — quantized uploads extend each array
-    #: with entries no sampler ever dereferences (NULL page ids / +inf
-    #: timestamps / invalid lanes)
+    #: pad fill per device array — uploads extend each array to its
+    #: power-of-two shape with entries no sampler ever dereferences
+    #: (NULL page ids / +inf timestamps / invalid lanes)
     _FILL = dict(page_table=NULL, pages_nbr=NULL, pages_eid=NULL,
                  pages_ts=np.inf, pages_valid=False,
                  page_tmin=np.inf, page_tmax=-np.inf, page_before=0)
@@ -447,17 +442,7 @@ class DeviceMirror:
                   pages_valid="valid")
     _DESC = ("page_tmin", "page_tmax", "page_before")
 
-    def __init__(self, *, donate: bool = True, quantize: bool = False):
-        self.donate = donate
-        # quantize=True rounds every device array's leading (row)
-        # dimension up to a power of two, so the mirrored shapes change
-        # O(log n) times as the graph grows instead of at every
-        # geometric reallocation. The jitted samplers retrace per
-        # distinct shape — for the serving wing (queries race ingest) an
-        # unquantized mirror would recompile sample_khop for every
-        # (growth step x batch bucket) pair, each a multi-hundred-ms
-        # stall on the query path.
-        self.quantize = quantize
+    def __init__(self):
         self.dev: Optional[dict] = None   # current device arrays
         self.version = -1                 # snapshot version mirrored
         self.snap_obj = None              # snapshot object the mirror was
@@ -468,30 +453,18 @@ class DeviceMirror:
         self.last_refresh_bytes = 0       # H2D payload of the last sync
         self.total_refresh_bytes = 0
 
-    def _host(self, a: np.ndarray) -> np.ndarray:
-        """CPU jax may zero-copy ALIAS an aligned numpy buffer, and the
-        snapshot arena mutates its host arrays in place between
-        versions.  The trainer mirror (donate=True) always re-syncs to
-        the newest version before sampling, so aliasing is harmless
-        there — but a serving handle pins its arrays across later
-        ingests, so the non-donated mirror must own private copies of
-        anything it uploads wholesale."""
-        return a if self.donate else np.array(a, copy=True)
-
     def _target_shape(self, name: str, host: np.ndarray) -> tuple:
-        rows = host.shape[0]
-        if self.quantize:
-            rows = 1 << max(3, int(rows - 1).bit_length())
+        rows = 1 << max(3, int(host.shape[0] - 1).bit_length())
         if name == "page_table":
             return (rows, 1 << max(0, int(host.shape[1] - 1).bit_length()))
         return (rows,) + host.shape[1:]
 
-    def _quantized(self, name: str, host: np.ndarray) -> np.ndarray:
-        """Host array padded to its quantized device shape (a private
-        copy either way — see ``_host``)."""
+    def _padded(self, name: str, host: np.ndarray) -> np.ndarray:
+        """Host array padded to its device shape, a private copy either
+        way: the snapshot arena mutates its host arrays in place."""
         tgt = self._target_shape(name, host)
         if tgt == host.shape:
-            return self._host(host)
+            return np.array(host, copy=True)
         out = np.full(tgt, self._FILL[name], host.dtype)
         out[tuple(slice(0, s) for s in host.shape)] = host
         return out
@@ -521,7 +494,7 @@ class DeviceMirror:
 
     def _upload_full(self, snap: GraphSnapshot) -> None:
         host = self._arrays(snap)
-        self.dev = {k: jnp.asarray(self._quantized(k, a))
+        self.dev = {k: jnp.asarray(self._padded(k, a))
                     for k, a in host.items()}
         self.last_refresh_bytes += sum(a.nbytes for a in host.values())
 
@@ -536,7 +509,7 @@ class DeviceMirror:
         if dev.shape == tgt and n == 0:
             return True
         if dev.shape != tgt or n * 2 >= of:
-            self.dev[name] = jnp.asarray(self._quantized(name, host))
+            self.dev[name] = jnp.asarray(self._padded(name, host))
             self.last_refresh_bytes += host.nbytes
             return True
         return False
@@ -572,9 +545,8 @@ class DeviceMirror:
                            host.dtype)
             wide[:, :upd.shape[1]] = upd
             upd = wide
-        f = _scatter_rows if self.donate else _scatter_rows_cow
-        self.dev[name] = f(dev, jnp.asarray(rows_p, jnp.int32),
-                           jnp.asarray(upd))
+        self.dev[name] = _scatter_rows(dev, jnp.asarray(rows_p, jnp.int32),
+                                       jnp.asarray(upd))
         self.last_refresh_bytes += upd.nbytes + rows_p.size * 4
 
     def _scatter_entries(self, hosts: dict, *idx: np.ndarray) -> None:
@@ -590,9 +562,8 @@ class DeviceMirror:
         at = tuple(idx_p)
         bits = np.stack([_bits(hosts[k][at]) for k in todo], axis=1)
         where = np.stack(idx_p, axis=1).astype(np.int32)
-        f = _scatter_group if self.donate else _scatter_group_cow
-        out = f(tuple(self.dev[k] for k in todo), jnp.asarray(where),
-                jnp.asarray(bits))
+        out = _scatter_group(tuple(self.dev[k] for k in todo),
+                             jnp.asarray(where), jnp.asarray(bits))
         self.dev.update(zip(todo, out))
         self.last_refresh_bytes += bits.nbytes + where.nbytes
 
@@ -610,10 +581,9 @@ class DeviceMirror:
                 or d.base_version != self.version):
             self._upload_full(snap)
         else:
-            if not self.donate:
-                # fresh dict per version: readers of the previous dict
-                # (pinned query handles) keep the old arrays
-                self.dev = dict(self.dev)
+            # fresh dict per version: readers of the previous dict
+            # (pinned query handles) keep the old arrays
+            self.dev = dict(self.dev)
             host = self._arrays(snap)
             self._scatter_rows("page_table", host["page_table"],
                                d.table_rows)
@@ -657,10 +627,11 @@ class TemporalSampler:
     """Paper's sampler: recent / uniform / window policies, k-hop.
 
     Device-resident incremental pipeline: the paged snapshot lives in
-    persistent device buffers; ``refresh()`` applies the snapshot's
-    ``SnapshotDelta`` as in-place row/cell scatters (donated buffers)
-    instead of re-uploading, and ``sample()`` runs the whole k-hop loop
-    as a single jitted dispatch."""
+    the graph's one device ``mirror`` (:class:`DeviceMirror`);
+    ``refresh()`` applies the snapshot's ``SnapshotDelta`` as
+    copy-on-write row/cell scatters instead of re-uploading, and
+    ``sample()`` runs the whole k-hop loop as a single jitted dispatch.
+    The serving wing publishes this same mirror."""
 
     def __init__(self, g_or_snap, fanouts: Sequence[int],
                  policy: str = "recent", window: float = 0.0,
@@ -691,9 +662,8 @@ class TemporalSampler:
         # machine, request seq, hop) into this so results are
         # independent of request arrival order across processes
         self.base_key = self._key
-        # persistent device mirror of the snapshot (donated in-place
-        # scatters: the trainer's sampler is the single consumer)
-        self._mirror = DeviceMirror(donate=True)
+        # the graph's one device mirror (the serving wing publishes it)
+        self.mirror = DeviceMirror()
 
     def _on_device(self):
         """Placement scope for mirror uploads + sampling dispatches."""
@@ -708,56 +678,8 @@ class TemporalSampler:
         with trace.span("sampler.refresh") as sp:
             self.snap = snap
             with self._on_device():
-                self._sync_device()
-            sp.set(bytes=self.last_refresh_bytes)
-
-    # -- device mirror maintenance (see DeviceMirror) ------------------
-    # The _dev/_dev_version/refresh-bytes surface predates the mirror
-    # extraction; tests and benches poke it (including assigning
-    # ``smp._dev = None`` to force a full upload), so it stays as
-    # delegating properties.
-    @property
-    def _dev(self):
-        return self._mirror.dev
-
-    @_dev.setter
-    def _dev(self, value):
-        self._mirror.dev = value
-
-    @property
-    def _dev_version(self) -> int:
-        return self._mirror.version
-
-    @_dev_version.setter
-    def _dev_version(self, value: int) -> None:
-        self._mirror.version = value
-
-    @property
-    def _dev_snap(self):
-        return self._mirror.snap_obj
-
-    @_dev_snap.setter
-    def _dev_snap(self, value) -> None:
-        self._mirror.snap_obj = value
-
-    @property
-    def last_refresh_bytes(self) -> int:
-        return self._mirror.last_refresh_bytes
-
-    @last_refresh_bytes.setter
-    def last_refresh_bytes(self, value: int) -> None:
-        self._mirror.last_refresh_bytes = value
-
-    @property
-    def total_refresh_bytes(self) -> int:
-        return self._mirror.total_refresh_bytes
-
-    @total_refresh_bytes.setter
-    def total_refresh_bytes(self, value: int) -> None:
-        self._mirror.total_refresh_bytes = value
-
-    def _sync_device(self):
-        return self._mirror.sync(self.snap)
+                self.mirror.sync(snap)
+            sp.set(bytes=self.mirror.last_refresh_bytes)
 
     # -- sampling ------------------------------------------------------
     def request_key(self, req_machine: int, seq: int, hop: int):
@@ -776,7 +698,7 @@ class TemporalSampler:
 
     def _dispatch(self, targets, times, tmask,
                   fanouts: Optional[Tuple[int, ...]] = None, key=None):
-        dev = self._sync_device()
+        dev = self.mirror.sync(self.snap)
         if key is not None:
             sub = key
         elif self.policy in ("uniform", "window"):

@@ -116,7 +116,7 @@ class DistributedSamplerSystem:
             for r, s in enumerate(self.samplers[m]):
                 with self._locks[m][r]:
                     s.refresh(self.snaps[m])
-                total += s.last_refresh_bytes
+                total += s.mirror.last_refresh_bytes
         self.last_refresh_bytes = total
         self.total_refresh_bytes += total
         return total

@@ -10,7 +10,7 @@ vectorized-jnp sampler and the Pallas kernel consume them directly.
 The snapshot is incremental: pages are immutable once full, so a snapshot
 refresh only appends/overwrites descriptor rows and the arena suffix that
 changed since the last refresh (mirroring the paper's "update without
-rebuild" property; see bench_graph_update.py).
+rebuild" property; see tests/test_snapshot_delta.py).
 
 Besides t_min/t_max, each page carries ``page_before``: how many valid
 edges the older pages of its node's chain hold. With it a sampler finds

@@ -3,7 +3,7 @@
 Power-law degrees via pareto node weights with arbitrary id assignment
 (matches the identity-hash partitioning assumption, §4.4). Optional
 concept drift: node popularity re-draws over time, so continuous
-retraining has something real to adapt to (used by bench_continuous).
+retraining has something real to adapt to.
 Node/edge features are deterministic functions of ids (splittable across
 partitions without communication).
 """

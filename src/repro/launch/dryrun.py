@@ -2,8 +2,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # ^ The two lines above MUST be the first lines of this module, before ANY
 # other import (jax locks the device count at first init). This module is
-# the ONLY place the 512-placeholder-device env is set; smoke tests and
-# benchmarks see the real single CPU device.
+# the ONLY place the 512-placeholder-device env is set; smoke tests see
+# the real single CPU device.
 
 # Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 # derive the three roofline terms from the compiled artifact.

@@ -6,11 +6,12 @@ device-resident snapshot mirror and fused k-hop sampler — through a
 versioned read handle so a query admitted mid-ingest never observes a
 half-applied ``SnapshotDelta``:
 
-* :class:`~repro.serve.handle.HandlePublisher` — copy-on-write device
-  mirror (``DeviceMirror(donate=False)``); each ingest publishes an
-  immutable :class:`~repro.serve.handle.SnapshotHandle` (snapshot
-  version + device arrays + model params), and the atomic handle swap
-  is the ONLY synchronization between ingest and query threads.
+* :class:`~repro.serve.handle.HandlePublisher` — publishes the
+  trainer's copy-on-write device mirror (``TemporalSampler.mirror``):
+  each ingest installs an immutable
+  :class:`~repro.serve.handle.SnapshotHandle` (snapshot version + device
+  arrays + model params), and the atomic handle swap is the ONLY
+  synchronization between ingest and query threads.
 * :class:`~repro.serve.admission.AdmissionQueue` — batched admission:
   requests collect up to a size/timeout budget and pad to a power of
   two, so serving reuses the trainer's jit cache.

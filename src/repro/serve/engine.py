@@ -83,7 +83,6 @@ class QueryResult:
     kind: str
     tier: str
     version: int
-    latency_s: float
     scores: Optional[np.ndarray] = None
     emb: Optional[np.ndarray] = None
     nbrs: Optional[Dict[str, Any]] = None
@@ -94,7 +93,7 @@ class QueryEngine:
 
     Wire-up (see :meth:`attach` for the one-liner)::
 
-        pub = HandlePublisher(scan_pages=..., use_pallas=...)
+        pub = HandlePublisher(trainer.sampler.mirror, scan_pages=...)
         eng = QueryEngine(pub, cfg=trainer.cfg, state=trainer.state, ...)
         trainer.register_serving(eng)   # publishes on every ingest
         eng.start()
@@ -155,10 +154,11 @@ class QueryEngine:
     @classmethod
     def attach(cls, trainer, *, edgebank: Optional[EdgeBank] = None,
                history: int = 8, start: bool = True, **kw) -> "QueryEngine":
-        """Build a publisher + engine for ``trainer``, register the
-        serving hooks, and start the worker."""
+        """Build a publisher of the trainer's device mirror + an engine
+        for ``trainer``, register the serving hooks, and start the
+        worker."""
         pub = HandlePublisher(
-            scan_pages=trainer.sampler.scan_pages,
+            trainer.sampler.mirror, scan_pages=trainer.sampler.scan_pages,
             use_pallas=trainer.use_pallas, history=history)
         kw.setdefault("id_space_nodes", trainer.stream.n_nodes + 1)
         kw.setdefault("id_space_edges", len(trainer.stream) + 1)
@@ -225,14 +225,13 @@ class QueryEngine:
         dst = np.atleast_1d(np.asarray(dst, np.int64))
         ts = np.atleast_1d(np.asarray(ts, np.float32))
         self._c_queries.add()
-        t0 = time.perf_counter()
         if (self.edgebank is not None
                 and self.queue.depth >= self.saturate_depth):
-            return self._edgebank_answer(src, dst, ts, t0)
-        q = Query("link", src, dst, ts, QueryFuture(), t0)
+            return self._edgebank_answer(src, dst, ts)
+        q = Query("link", src, dst, ts, QueryFuture(), time.perf_counter())
         if not self.queue.submit(q):
             if self.edgebank is not None:
-                return self._edgebank_answer(src, dst, ts, t0)
+                return self._edgebank_answer(src, dst, ts)
             raise RuntimeError("serving queue full and no fallback tier")
         return q.future
 
@@ -252,14 +251,12 @@ class QueryEngine:
                                "non-parametric fallback tier)")
         return q.future
 
-    def _edgebank_answer(self, src, dst, ts, t0) -> QueryResult:
+    def _edgebank_answer(self, src, dst, ts) -> QueryResult:
         with trace.span("serve.fallback", pairs=len(src)):
             scores = self.edgebank.predict(src, dst, ts)
-        lat = time.perf_counter() - t0
         self._c_fallback.add()
         return QueryResult(kind="link", tier="edgebank",
-                           version=self.edgebank.version,
-                           latency_s=lat, scores=scores)
+                           version=self.edgebank.version, scores=scores)
 
     # -- worker ----------------------------------------------------------
     def start(self) -> "QueryEngine":
@@ -410,10 +407,8 @@ class QueryEngine:
                 if link:
                     nbrs["dst_ids"] = nbr_ids[m + off:m + off + k]
                     nbrs["dst_mask"] = nbr_mask[m + off:m + off + k]
-            lat = time.perf_counter() - q.t_submit
             res = QueryResult(
-                kind=q.kind, tier="gnn", version=handle.version,
-                latency_s=lat, nbrs=nbrs,
+                kind=q.kind, tier="gnn", version=handle.version, nbrs=nbrs,
                 scores=out[off:off + k].copy() if link else None,
                 emb=None if link else out[off:off + k].copy())
             q.future.set_result(res)

@@ -1,13 +1,11 @@
 """Versioned snapshot read handles — the ingest/query synchronization.
 
-The trainer's own sampler mirror updates with DONATED in-place scatters
-(single consumer); a concurrent reader of that mirror could observe a
-half-applied delta or a donated-away buffer.  The publisher therefore
-maintains a second mirror with ``donate=False``: every publish yields a
-fresh device dict whose updated arrays are NEW buffers (copy-on-write
-at array granularity — unchanged arrays are shared), so a handle pinned
-by an in-flight query keeps a complete, immutable view of its version
-no matter how many deltas land afterwards.
+The publisher publishes the trainer's own device mirror
+(``TemporalSampler.mirror``, a copy-on-write :class:`DeviceMirror`):
+every sync that changes anything yields a fresh device dict whose
+updated arrays are NEW buffers (unchanged arrays are shared), so a
+handle pinned by an in-flight query keeps a complete, immutable view of
+its version no matter how many deltas land afterwards.
 
 Swap protocol: ``publish`` builds the :class:`SnapshotHandle` off to
 the side and installs it with a single reference assignment (atomic
@@ -53,12 +51,9 @@ class HandlePublisher:
     served from, even after newer versions landed.
     """
 
-    def __init__(self, *, scan_pages: int = 16, use_pallas: bool = False,
-                 history: int = 8):
-        # donate=False: copy-on-write arrays so pinned handles stay
-        # valid; quantize=True: pow2-bucketed device shapes so the
-        # query-path samplers retrace O(log n) times under graph growth
-        self._mirror = DeviceMirror(donate=False, quantize=True)
+    def __init__(self, mirror: DeviceMirror, *, scan_pages: int = 16,
+                 use_pallas: bool = False, history: int = 8):
+        self.mirror = mirror
         self.scan_pages = int(scan_pages)
         self.use_pallas = use_pallas
         self._current: Optional[SnapshotHandle] = None
@@ -70,11 +65,12 @@ class HandlePublisher:
 
     def publish(self, snap: GraphSnapshot, *, params: Any = None,
                 t_max: float = 0.0, n_events: int = 0) -> SnapshotHandle:
-        """Sync the copy-on-write mirror to ``snap`` and install a new
-        handle.  The old handle (and every handle in history) remains
-        fully readable."""
+        """Sync the mirror to ``snap`` (free where the trainer's
+        ``sampler.refresh`` already did) and install a new handle.  The
+        old handle (and every handle in history) remains fully
+        readable."""
         with self._lock:
-            dev = self._mirror.sync(snap)
+            dev = self.mirror.sync(snap)
             prev = self._current
             if params is None and prev is not None:
                 params = prev.params

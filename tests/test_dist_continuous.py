@@ -216,7 +216,7 @@ def test_scheduler_refresh_chains_deltas():
     assert max(deltas) < 3 * min(deltas), deltas
     for m in range(P):
         for s in sys_.samplers[m]:
-            assert s._dev_version == sys_.snaps[m].version
+            assert s.mirror.version == sys_.snaps[m].version
     # chained mirrors sample identically to freshly-built ones
     fresh = DistributedSamplerSystem(parts, 1, (4, 4), scan_pages=16)
     seeds = np.arange(64, dtype=np.int64)

@@ -30,6 +30,8 @@ from repro.data.events import synth_ctdg
 from repro.dist.continuous import DistributedContinuousTrainer
 from repro.dist.transport import LocalTransport, RpcTransport
 from repro.launch import multihost
+from repro.obs import report as obs_report
+from repro.obs import trace as obs_trace
 
 WORKER = Path(__file__).resolve().parent / "_multihost_worker.py"
 P_, G_ = 2, 2          # 2 machines x 2 trainer ranks = 4 workers
@@ -74,12 +76,13 @@ def _reference_rounds(run_cfg: dict):
     return tr, rounds
 
 
-def _launch_workers(run_cfg: dict, subprocess_env: dict):
+def _launch_workers(run_cfg: dict, subprocess_env: dict, **env):
     # let the workers share CI's persistent XLA compile cache
     extra = {k: os.environ[k] for k in (
         "JAX_COMPILATION_CACHE_DIR",
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
         "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES") if k in os.environ}
+    extra.update(env)
     outs = multihost.launch(
         [sys.executable, str(WORKER), json.dumps(run_cfg)],
         n_processes=P_, n_local_devices=G_,
@@ -115,6 +118,37 @@ def _assert_parity(run_cfg, results, ref_rounds):
     # partitioned ingest: dispatch bytes accounted on every process
     assert all(rd["dispatch_bytes"] > 0
                for r in results for rd in r["rounds"])
+
+
+def _assert_fleet_trace(results, out_dir):
+    """The traced fleet's merged timeline: every worker on one clock,
+    the in-flight step and the state prefetch on lanes of their own,
+    the step concurrent with the host's prefetch of the next batch, and
+    span totals agreeing with the round metrics the workers computed
+    from the same intervals (``trace.stage`` feeds both)."""
+    path = multihost.collect_fleet_trace(results,
+                                         str(out_dir / "fleet.json"))
+    assert path, "traced fleet produced no worker trace files"
+    merged = obs_trace.load_trace(path)
+    xs = [e for e in merged["traceEvents"] if e.get("ph") == "X"]
+    assert {e["pid"] for e in xs} == set(range(P_))
+    w0 = [e for e in xs if e["pid"] == 0]
+    steps, prefetch, state_pf = (
+        [e for e in w0 if e["name"] == n]
+        for n in ("device.step", "pipeline.prefetch", "state.prefetch"))
+    assert steps and state_pf
+    main_tids = {e["tid"] for e in prefetch}
+    assert main_tids.isdisjoint(e["tid"] for e in steps + state_pf)
+    assert any(a["ts"] < b["ts"] + b["dur"] and b["ts"] < a["ts"] + a["dur"]
+               for a in steps for b in prefetch)
+    summary = obs_report.summarize(merged, pid=0)
+    rounds = next(r for r in results if r["process_id"] == 0)["rounds"]
+    for kind, field in (("sample", "sample_s"), ("fetch", "fetch_s"),
+                        ("step", "step_s"), ("state.wait", "state_wait_s")):
+        metric = sum(m[field] for m in rounds)
+        span = summary["spans"].get(kind, {}).get("total_s", 0.0)
+        assert abs(span - metric) <= max(0.10 * metric, 0.05), \
+            (kind, span, metric)
 
 
 @pytest.mark.slow
@@ -185,6 +219,33 @@ def test_two_process_sharded_state_parity_tgn(subprocess_env):
             # fenced default: nothing ever served stale
             assert rd["state_stale_served"] == 0
             assert sum(rd["state_wire_bytes_per_part"]) > 0
+
+
+@pytest.mark.slow
+def test_two_process_sharded_state_coalesced_tgat(subprocess_env, tmp_path):
+    """The owner-sharded TGAT fleet reads all of a global batch's remote
+    rows in one ``state_batch`` frame per foreign peer: at most P-1
+    wire round trips per batch, at least 3x fewer than the per-table
+    path would take, with the losses of the replicated in-process
+    trainer. (TGN reads its memory again after every commit, so the
+    budget is TGAT's.) The fleet runs traced, and its merged timeline
+    is checked too."""
+    run_cfg = _run_cfg("tgat")
+    _, ref = _reference_rounds(run_cfg)        # replicated reference
+    run_cfg["trainer"] = dict(run_cfg["trainer"], state="sharded")
+    results = _launch_workers(
+        run_cfg, subprocess_env, REPRO_TRACE="1",
+        **{multihost._ENV["trace_dir"]: str(tmp_path)})
+    _assert_parity(run_cfg, results, ref)
+    for r in results:
+        assert r["state"]["mode"] == "sharded"
+        for rd in r["rounds"]:
+            assert 0 < rd["state_trips_per_batch"] <= P_ - 1, rd
+            assert rd["state_stale_served"] == 0
+    base = sum(r["state"]["baseline_trips"] for r in results)
+    trips = sum(r["state"]["round_trips"] for r in results)
+    assert base >= 3 * trips, (base, trips)
+    _assert_fleet_trace(results, tmp_path)
 
 
 @pytest.mark.slow

@@ -27,7 +27,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs.tgn_gdelt import tgat, tgn
 from repro.core.continuous import ContinuousTrainer
 from repro.core.dgraph import DynamicGraph
-from repro.core.sampling import oracle_sample
+from repro.core.sampling import DeviceMirror, oracle_sample, sample_khop
 from repro.core.snapshot import build_snapshot, refresh_snapshot
 from repro.data.events import synth_ctdg
 from repro.obs import trace
@@ -107,15 +107,14 @@ def test_edgebank_matches_bruteforce(seed, undirected, window):
 
 def test_pinned_handle_survives_later_deltas():
     """Sampling against a pinned handle is bit-identical before and
-    after newer versions publish — the old device arrays were NOT
-    donated away by the ingest-side scatters."""
-    from repro.core.sampling import sample_khop
+    after newer versions publish — the mirror's scatters write new
+    buffers and leave the pinned ones as they were."""
     stream = synth_ctdg(n_nodes=40, n_events=300, d_node=D, d_edge=D,
                         seed=3)
     g = DynamicGraph(threshold=8, undirected=True)
     g.add_edges(stream.src[:100], stream.dst[:100], stream.ts[:100])
     snap = build_snapshot(g)
-    pub = HandlePublisher(scan_pages=16)
+    pub = HandlePublisher(DeviceMirror(), scan_pages=16)
     hA = pub.publish(snap, n_events=100)
     seeds = np.arange(12, dtype=np.int64)
     t_hi = np.full(12, float(stream.ts.max()) + 1, np.float32)
@@ -145,6 +144,42 @@ def test_pinned_handle_survives_later_deltas():
     assert pub.get(hA.version) is hA
 
 
+def test_trainer_and_engine_share_one_mirror():
+    """The serving wing publishes the trainer's own device mirror: the
+    published arrays ARE the sampler's, a publish moves no bytes of its
+    own, and a handle pinned before a later ingest samples
+    bit-identically after it."""
+    stream = synth_ctdg(n_nodes=40, n_events=300, d_node=D, d_edge=D,
+                        seed=13)
+    tr = _trainer(stream)
+    eng = QueryEngine.attach(tr, start=False)
+    mirror = tr.sampler.mirror
+    tr.ingest(stream.slice(0, 150))
+    h = eng.publisher.current()
+    assert h.dev is mirror.dev and h.version == mirror.version
+    assert mirror.total_refresh_bytes == tr._refresh_bytes > 0
+    seeds = np.arange(12, dtype=np.int64)
+    t_hi = np.full(12, float(stream.ts.max()) + 1, np.float32)
+
+    def hop0(handle):
+        [l0] = sample_khop(handle.dev, seeds, t_hi, fanouts=(4,),
+                           policy="recent", scan_pages=handle.scan_pages)
+        return [np.asarray(a)
+                for a in (l0.nbr_ids, l0.nbr_eids, l0.nbr_ts, l0.mask)]
+
+    before = hop0(h)
+    tr.ingest(stream.slice(150, 300))
+    new = eng.publisher.current()
+    assert new.version > h.version and new.dev is mirror.dev
+    assert mirror.total_refresh_bytes == tr._refresh_bytes
+    for a, b in zip(before, hop0(h)):
+        np.testing.assert_array_equal(a, b)
+    # ... while the newest handle sees the second ingest's edges
+    assert not all(np.array_equal(a, b)
+                   for a, b in zip(before, hop0(new)))
+    eng.stop()
+
+
 # ---------------------------------------------------------------------------
 # ingest || query: every response consistent with exactly one version
 # ---------------------------------------------------------------------------
@@ -157,13 +192,12 @@ def test_interleaved_add_delete_query_consistency(seed):
     delete batches (each published as a new version) while this thread
     samples pinned handles; every sample must equal the oracle on the
     graph replayed to exactly that version's operation prefix."""
-    from repro.core.sampling import sample_khop
     n_nodes = 40
     stream = synth_ctdg(n_nodes=n_nodes, n_events=240, d_node=D,
                         d_edge=D, seed=seed)
     rng = np.random.default_rng(seed + 1)
     g = DynamicGraph(threshold=8, undirected=True)
-    pub = HandlePublisher(scan_pages=16, history=64)
+    pub = HandlePublisher(DeviceMirror(), scan_pages=16, history=64)
     oplog = []
     version_ops = {}
     vlock = threading.Lock()

@@ -15,7 +15,7 @@ def _assert_dev_equals_fresh(smp: TemporalSampler, g: DynamicGraph):
     """Device arrays == from-scratch snapshot on every live row; spare
     capacity rows in the page table must be empty (NULL) because the
     sampler clip-gathers them for out-of-range targets."""
-    dev = smp._sync_device()
+    dev = smp.mirror.sync(smp.snap)
     fresh = build_snapshot(g, page_cap=smp.snap.page_cap)
     nb, n = fresh.n_pages, fresh.n_live
     width = fresh.page_table.shape[1]
@@ -145,13 +145,13 @@ def test_append_only_transfer_bytes_sublinear():
         add_batch()
     snap = build_snapshot(g)
     smp = TemporalSampler(snap, (4,), policy="recent")
-    smp._sync_device()
+    smp.mirror.sync(smp.snap)
     per_round = []
     for _ in range(40):
         add_batch()
         snap = refresh_snapshot(g, snap)
         smp.refresh(snap)
-        per_round.append(smp.last_refresh_bytes)
+        per_round.append(smp.mirror.last_refresh_bytes)
     full_bytes = (snap.page_table.nbytes + snap.page_tmin.nbytes
                   + snap.page_tmax.nbytes + snap.nbr.nbytes
                   + snap.eid.nbytes + snap.ts.nbytes + snap.valid.nbytes)
@@ -191,7 +191,7 @@ def test_stale_sampler_falls_back_to_full_upload():
     g.add_edges(np.arange(20), np.arange(20) + 1, np.arange(20.0))
     snap = build_snapshot(g)
     smp = TemporalSampler(snap, (4,), policy="recent")
-    smp._sync_device()
+    smp.mirror.sync(smp.snap)
     for r in range(3):            # refresh the snapshot WITHOUT syncing
         g.add_edges(np.arange(20), np.arange(20) + 1,
                     20.0 * (r + 1) + np.arange(20.0))

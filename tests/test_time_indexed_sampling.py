@@ -129,10 +129,8 @@ def _brute_before(g, snap):
     return out
 
 
-@pytest.mark.parametrize("donate,quantize", [(True, False),
-                                             (False, True)])
 def test_mirror_refresh_across_width_buckets_matches_fresh_upload(
-        tmp_path, donate, quantize):
+        tmp_path):
     """A hub's chain grows through several power-of-two widths while
     other nodes join, edges are deleted and old blocks offloaded; the
     delta-synced mirror equals a fresh upload of the same snapshot in
@@ -140,7 +138,7 @@ def test_mirror_refresh_across_width_buckets_matches_fresh_upload(
     rng = np.random.default_rng(5)
     g = DynamicGraph(threshold=4, min_block=2, undirected=True)
     snap = None
-    mirror = DeviceMirror(donate=donate, quantize=quantize)
+    mirror = DeviceMirror()
     widths = set()
     t = 0.0
     for r in range(12):
@@ -160,7 +158,7 @@ def test_mirror_refresh_across_width_buckets_matches_fresh_upload(
                 else refresh_snapshot(g, snap))
         dev = mirror.sync(snap)
         widths.add(dev["page_table"].shape[1])
-        fresh = DeviceMirror(donate=donate, quantize=quantize)
+        fresh = DeviceMirror()
         fresh.sync(build_snapshot(g, page_cap=snap.page_cap))
         got, want = _dev_host(mirror), _dev_host(fresh)
         nb, nl = snap.n_pages, snap.n_live
